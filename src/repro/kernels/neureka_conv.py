@@ -10,80 +10,116 @@ Hardware adaptation notes (see DESIGN.md §2):
   * N-EUREKA is output-stationary with 6x6 PEs over 8x8 input tiles and
     28-channel input chunks (bandwidth-limited).  The TPU mapping keeps the
     output-stationary reduction (accumulators in VMEM scratch across the
-    input-channel grid axis) but uses MXU-aligned channel blocks; spatial
-    tiles are row-strips of the feature map, which at XR feature-map sizes
-    fit VMEM whole.
+    input-channel grid axis) but uses MXU-aligned channel blocks; the
+    padded feature map stays whole in VMEM (the INPUTBUFFER analogue).
   * Bit-serial weight arithmetic becomes sub-byte *packed streaming*: HBM
     traffic scales with the weight bit-width exactly as MRAM cycles do.
-  * Strides 1 and 2 are supported (MobileNet-V2 needs stride 2); striding is
-    applied when gathering the im2col view inside the kernel.
+  * Strides 1 and 2 are supported (MobileNet-V2 needs stride 2).  The
+    wrapper splits the padded map into its ``stride**2`` spatial phases
+    (``x[pi::s, pj::s]``), so every 3x3 tap is a contiguous window of one
+    phase and the kernel never issues a strided load.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.qmatmul import _unpack_block, qmatmul_int8
+from repro.kernels.qmatmul import (INT_DOT_MAX_K, _pad_to, _row, int_dot_nt,
+                                   qmatmul_int8, requant_u8, unpack_fields)
+
+# a whole 112x112 feature map plus its int32 accumulator exceeds the
+# default scoped VMEM; v5e has 128 MiB of it
+_VMEM_LIMIT = 96 * 1024 * 1024
 
 
-def _requant_f32(acc: jax.Array, mult: jax.Array, bias: jax.Array) -> jax.Array:
-    """NORMQUANT projection: int32 acc -> uint8 (float-rescale formulation)."""
-    y = jnp.round(acc.astype(jnp.float32) * mult) + bias.astype(jnp.float32)
-    return jnp.clip(y, 0.0, 255.0).astype(jnp.uint8)
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _channel_block(c: int, blk: int, mult: int) -> int:
+    """Channel block: ``blk``, or all of ``c`` (rounded up to ``mult``)
+    when that is smaller, so a narrow layer is one full-width block."""
+    return min(blk, _round_up(c, mult))
+
+
+def _phases(x: jax.Array, stride: int, ho: int, wo: int) -> jax.Array:
+    """'same'-padded (H, W, C) map -> (stride**2, Hq, Wq, C) phases.
+
+    Phase ``pi*stride + pj`` is ``xp[pi::stride, pj::stride]``, so tap
+    (i, j) of output pixel (r, c) sits at phase ``(i%s)*s + j%s``, row
+    ``r + i//s``, column ``c + j//s``."""
+    h, w_, _ = x.shape
+    s = stride
+    hq, wq = ho + 2 // s, wo + 2 // s
+    xp = jnp.pad(x, ((1, s * hq - h - 1), (1, s * wq - w_ - 1), (0, 0)))
+    return jnp.stack([xp[pi::s, pj::s] for pi in range(s) for pj in range(s)])
+
+
+def _tap(i: int, j: int, s: int):
+    """(phase, row offset, column offset) of 3x3 tap (i, j) at stride s."""
+    return (i % s) * s + j % s, i // s, j // s
 
 
 # ---------------------------------------------------------------------------
 # 3x3 dense:  out[h, w, co] = sum_{i,j,ci} x[s*h+i, s*w+j, ci] * W[co, i, j, ci]
-# Grid: (cout blocks, cin blocks); the padded input strip stays whole in VMEM
-# (the INPUTBUFFER analogue); cin is the innermost (reduction) axis.
+# Grid: (cout blocks, cin blocks); the phase-split input stays whole in VMEM
+# (the INPUTBUFFER analogue); cin is the innermost (reduction) axis.  Each
+# tap is one MXU dot per packed field plane — no im2col concatenation.
 # ---------------------------------------------------------------------------
+
+def _strip_rows(ho: int) -> int:
+    """Output rows per strip: the largest divisor of ``ho`` up to 8, so a
+    strip's working set is a few vregs deep instead of the whole map."""
+    return max(r for r in range(1, min(ho, 8) + 1) if ho % r == 0)
+
 
 def _dense3x3_kernel(x_ref, wp_ref, mult_ref, bias_ref, o_ref, acc_ref, *,
                      bits: int, n_ci: int, stride: int, ho: int, wo: int):
     ci = pl.program_id(1)
+    f = 8 // bits
+    rows = _strip_rows(ho)
 
     @pl.when(ci == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)               # (Hp, Wp, bci)
-    bci = x.shape[-1]
-    # im2col with stride: (ho*wo, 9*bci) — the DISPATCHINGNETWORK view
-    cols = []
-    for i in range(3):
-        for j in range(3):
-            patch = jax.lax.slice(
-                x, (i, j, 0), (i + (ho - 1) * stride + 1,
-                               j + (wo - 1) * stride + 1, bci),
-                (stride, stride, 1))
-            cols.append(patch.reshape(ho * wo, bci))
-    xm = jnp.concatenate(cols, axis=-1)            # (ho*wo, 9*bci)
+    taps = [(_tap(i, j, stride), unpack_fields(wp_ref[i * 3 + j], bits))
+            for i in range(3) for j in range(3)]     # planes: (bco, bci/f)
 
-    w = _unpack_block(wp_ref[...].reshape(wp_ref.shape[0], -1), bits)
-    w = w[:, : 9 * bci]                            # (bco, 9*bci)
-    acc_ref[...] += jax.lax.dot_general(
-        xm, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    def strip(r, carry):
+        r0 = r * rows
+        acc = None
+        for (ph, di, dj), planes in taps:
+            for fj, w in enumerate(planes):
+                patch = x_ref[ph * f + fj, pl.ds(r0 + di, rows),
+                              dj:dj + wo, :]
+                term = int_dot_nt(patch.reshape(rows * wo, -1), w)
+                acc = term if acc is None else acc + term
+        span = pl.ds(r0 * wo, rows * wo)
+        acc_ref[span, :] = acc_ref[span, :] + acc
+        return carry
+
+    jax.lax.fori_loop(0, ho // rows, strip, 0)
 
     @pl.when(ci == n_ci - 1)
     def _requant():
-        o_ref[...] = _requant_f32(
-            acc_ref[...], mult_ref[...][None, :], bias_ref[...][None, :])
+        o_ref[...] = requant_u8(acc_ref[...], mult_ref[...], bias_ref[...])
 
 
 def conv3x3_dense(x: jax.Array, packed: jax.Array, mult: jax.Array,
                   bias: jax.Array, *, bits: int, cin: int, stride: int = 1,
-                  bco: int = 32, bci: int = 32,
+                  bco: int = 128, bci: int = 512,
                   interpret: bool = False) -> jax.Array:
     """x (H, W, Cin) uint8, packed (Cout, 3, 3, Cin/f) -> (Ho, Wo, Cout) uint8.
 
     'same' padding for stride 1; for stride 2 output is ceil(H/2) (pad=1).
+    Input channels are de-interleaved by packed field, like the matmul
+    activations (:func:`repro.kernels.qmatmul.deinterleave`).
     """
     f = 8 // bits
     h, w_, c = x.shape
@@ -91,107 +127,115 @@ def conv3x3_dense(x: jax.Array, packed: jax.Array, mult: jax.Array,
     assert c == cin
     ho = -(-h // stride)
     wo = -(-w_ // stride)
+    bci = _channel_block(c, min(bci, INT_DOT_MAX_K), 8 * f)
+    bco = _channel_block(cout, bco, 8)
+    cp = _round_up(c, bci)
+    cop = _round_up(cout, bco)
 
-    # spatial halo pad + channel pad to block multiple
-    cpad = (-c) % bci
-    hpad = (ho - 1) * stride + 3 - h - 1
-    wpad = (wo - 1) * stride + 3 - w_ - 1
-    xp = jnp.pad(x, ((1, max(hpad, 1)), (1, max(wpad, 1)), (0, cpad)))
-    # weights: (Cout, 3, 3, Cin/f) -> pad Cout and Cin(packed) to blocks
-    copad = (-cout) % bco
-    wp = jnp.pad(packed, ((0, copad), (0, 0), (0, 0), (0, (cpad // f) if cpad else 0)))
-    # reorder so the packed reduction axis blocks as (3,3,bci/f) contiguous
-    wp = wp.reshape(cout + copad, 9, -1)
-    multp = jnp.pad(mult.astype(jnp.float32), (0, copad))
-    biasp = jnp.pad(bias.astype(jnp.int32), (0, copad))
+    ph = _phases(_pad_to(x, 2, bci), stride, ho, wo)       # (P, Hq, Wq, cp)
+    n_ph, hq, wq, _ = ph.shape
+    # field planes: channel b*f + j -> plane j, index b
+    xd = ph.reshape(n_ph, hq, wq, cp // f, f).transpose(0, 4, 1, 2, 3)
+    xd = xd.reshape(n_ph * f, hq, wq, cp // f)
+    # weights: (Cout, 3, 3, Cin/f) -> (9 taps, Cout, Cin/f), blocks padded
+    wp = _pad_to(_pad_to(packed, 0, bco), 3, bci // f)
+    wp = wp.reshape(cop, 9, cp // f).transpose(1, 0, 2)
 
-    n_ci = (c + cpad) // bci
-    n_co = (cout + copad) // bco
-    hp, wpd = xp.shape[0], xp.shape[1]
-
+    n_ci = cp // bci
     out = pl.pallas_call(
         functools.partial(_dense3x3_kernel, bits=bits, n_ci=n_ci,
                           stride=stride, ho=ho, wo=wo),
-        grid=(n_co, n_ci),
+        grid=(cop // bco, n_ci),
         in_specs=[
-            pl.BlockSpec((hp, wpd, bci), lambda co, ci: (0, 0, ci)),
-            pl.BlockSpec((bco, 9, bci // f), lambda co, ci: (co, 0, ci)),
-            pl.BlockSpec((bco,), lambda co, ci: (co,)),
-            pl.BlockSpec((bco,), lambda co, ci: (co,)),
+            pl.BlockSpec((n_ph * f, hq, wq, bci // f),
+                         lambda co, ci: (0, 0, 0, ci)),
+            pl.BlockSpec((9, bco, bci // f), lambda co, ci: (0, co, ci)),
+            pl.BlockSpec((1, bco), lambda co, ci: (0, co)),
+            pl.BlockSpec((1, bco), lambda co, ci: (0, co)),
         ],
         out_specs=pl.BlockSpec((ho * wo, bco), lambda co, ci: (0, co)),
-        out_shape=jax.ShapeDtypeStruct((ho * wo, cout + copad), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((ho * wo, cop), jnp.uint8),
         scratch_shapes=[pltpu.VMEM((ho * wo, bco), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(xp, wp, multp, biasp)
+    )(xd, wp, _row(mult, jnp.float32, bco), _row(bias, jnp.int32, bco))
     return out[:, :cout].reshape(ho, wo, cout)
 
 
 # ---------------------------------------------------------------------------
 # 3x3 depthwise: out[h, w, c] = sum_{i,j} x[s*h+i, s*w+j, c] * W[c, i, j]
 # Bit-serial in silicon with parallel accumulator update; on TPU a VPU
-# (elementwise) kernel over channel blocks.
+# (elementwise) kernel over lane-dense channel blocks.
 # ---------------------------------------------------------------------------
 
 def _dw3x3_kernel(x_ref, wp_ref, mult_ref, bias_ref, o_ref, *,
                   bits: int, stride: int, ho: int, wo: int):
-    x = x_ref[...].astype(jnp.int32)               # (Hp, Wp, bc)
-    bc = x.shape[-1]
-    w = _unpack_block(wp_ref[...], bits)[:, :9]    # (bc, 9)
-    acc = jnp.zeros((ho, wo, bc), jnp.int32)
-    for i in range(3):
-        for j in range(3):
-            patch = jax.lax.slice(
-                x, (i, j, 0), (i + (ho - 1) * stride + 1,
-                               j + (wo - 1) * stride + 1, bc),
-                (stride, stride, 1))
-            acc = acc + patch * w[:, i * 3 + j][None, None, :]
-    o_ref[...] = _requant_f32(acc, mult_ref[...][None, None, :],
-                              bias_ref[...][None, None, :])
+    f = 8 // bits
+    rows = _strip_rows(ho)
+    planes = unpack_fields(wp_ref[...], bits)          # f x (ceil(9/f), bc)
+    taps = [(_tap(i, j, stride),
+             planes[t % f][t // f:t // f + 1, :])      # level row (1, bc)
+            for t, (i, j) in enumerate((i, j) for i in range(3)
+                                       for j in range(3))]
+
+    def strip(r, carry):
+        r0 = r * rows
+        acc = None
+        for (ph, di, dj), w in taps:
+            patch = x_ref[ph, pl.ds(r0 + di, rows), dj:dj + wo, :]
+            term = patch.astype(jnp.int32) * w
+            acc = term if acc is None else acc + term
+        o_ref[pl.ds(r0, rows), :, :] = requant_u8(acc, mult_ref[...],
+                                                  bias_ref[...])
+        return carry
+
+    jax.lax.fori_loop(0, ho // rows, strip, 0)
 
 
 def conv3x3_dw(x: jax.Array, packed: jax.Array, mult: jax.Array,
-               bias: jax.Array, *, bits: int, stride: int = 1, bc: int = 32,
+               bias: jax.Array, *, bits: int, stride: int = 1, bc: int = 128,
                interpret: bool = False) -> jax.Array:
-    """Depthwise 3x3; packed (C, ceil(9/f)) uint8 along the 9-tap axis."""
-    f = 8 // bits
+    """Depthwise 3x3; packed (C, ceil(9/f)) uint8 along the 9-tap axis.
+
+    The taps travel transposed, (ceil(9/f), C), so each tap's levels are a
+    lane-dense row over the channel block."""
     h, w_, c = x.shape
     ho = -(-h // stride)
     wo = -(-w_ // stride)
-    cpad = (-c) % bc
-    hpad = (ho - 1) * stride + 3 - h - 1
-    wpad = (wo - 1) * stride + 3 - w_ - 1
-    xp = jnp.pad(x, ((1, max(hpad, 1)), (1, max(wpad, 1)), (0, cpad)))
-    wp = jnp.pad(packed, ((0, cpad), (0, 0)))
-    multp = jnp.pad(mult.astype(jnp.float32), (0, cpad))
-    biasp = jnp.pad(bias.astype(jnp.int32), (0, cpad))
-    hp, wpd = xp.shape[0], xp.shape[1]
-    kp = wp.shape[1]
+    bc = _channel_block(c, bc, 8)
+    cp = _round_up(c, bc)
+    ph = _phases(_pad_to(x, 2, bc), stride, ho, wo)    # (P, Hq, Wq, cp)
+    n_ph, hq, wq, _ = ph.shape
+    wt = _pad_to(packed, 0, bc).T                       # (ceil(9/f), cp)
+    kf = wt.shape[0]
 
     out = pl.pallas_call(
-        functools.partial(_dw3x3_kernel, bits=bits, stride=stride, ho=ho, wo=wo),
-        grid=((c + cpad) // bc,),
+        functools.partial(_dw3x3_kernel, bits=bits, stride=stride, ho=ho,
+                          wo=wo),
+        grid=(cp // bc,),
         in_specs=[
-            pl.BlockSpec((hp, wpd, bc), lambda cb: (0, 0, cb)),
-            pl.BlockSpec((bc, kp), lambda cb: (cb, 0)),
-            pl.BlockSpec((bc,), lambda cb: (cb,)),
-            pl.BlockSpec((bc,), lambda cb: (cb,)),
+            pl.BlockSpec((n_ph, hq, wq, bc), lambda cb: (0, 0, 0, cb)),
+            pl.BlockSpec((kf, bc), lambda cb: (0, cb)),
+            pl.BlockSpec((1, bc), lambda cb: (0, cb)),
+            pl.BlockSpec((1, bc), lambda cb: (0, cb)),
         ],
         out_specs=pl.BlockSpec((ho, wo, bc), lambda cb: (0, 0, cb)),
-        out_shape=jax.ShapeDtypeStruct((ho, wo, c + cpad), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((ho, wo, cp), jnp.uint8),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(xp, wp, multp, biasp)
+    )(ph, wt, _row(mult, jnp.float32, bc), _row(bias, jnp.int32, bc))
     return out[:, :, :c]
 
 
 # ---------------------------------------------------------------------------
 # 1x1 dense (pointwise): a channel matmul — runs on the integer qmatmul
-# kernel (the silicon reuses the same PEs in bit-parallel mode).
+# kernel (the silicon reuses the same PEs in bit-parallel mode).  The whole
+# (padded) input-channel axis is one reduction block.
 # ---------------------------------------------------------------------------
 
 def conv1x1(x: jax.Array, packed: jax.Array, mult: jax.Array, bias: jax.Array,
             *, bits: int, cin: int, stride: int = 1,
-            bm: int = 256, bn: int = 128, bk: int = 128,
+            bm: int = 256, bn: int = 128,
             interpret: bool = False) -> jax.Array:
     h, w_, c = x.shape
     if stride != 1:
@@ -199,8 +243,8 @@ def conv1x1(x: jax.Array, packed: jax.Array, mult: jax.Array, bias: jax.Array,
         h, w_ = x.shape[0], x.shape[1]
     cout = packed.shape[0]
     xf = x.reshape(h * w_, c)
-    bk = min(bk, max(8 // bits, ((c + 7) // 8) * 8))
     out = qmatmul_int8(xf, packed, mult, bias, bits=bits, k_orig=cin,
-                       bm=min(bm, ((h * w_ + 7) // 8) * 8), bn=min(bn, ((cout + 7) // 8) * 8),
-                       bk=bk, interpret=interpret)
+                       bm=bm, bn=_channel_block(cout, bn, 8),
+                       bk=min(_round_up(c, 8 * (8 // bits)), INT_DOT_MAX_K),
+                       interpret=interpret)
     return out.reshape(h, w_, cout)

@@ -17,6 +17,14 @@ Two datapaths, mirroring N-EUREKA's two consumers:
   - float path  (LM serving):   x bf16/f32  @ W_packed -> f32
   - integer path (N-EUREKA pw): x uint8     @ W_packed -> int32 -> requant uint8
 
+Sub-byte fields are never re-interleaved into a (bn, bk) weight tile (a
+minor-dim reshape Mosaic cannot lower).  Field ``j`` of packed byte ``b``
+holds reduction element ``b*f + j``, so the wrapper de-interleaves the
+*activations* instead — ``x[:, j::f]`` becomes plane ``j`` of an
+``(f, M, K/f)`` array — and the kernel sums ``f`` dots of plane ``j``
+against field ``j`` of the packed block.  Scale and bias vectors travel as
+``(1, N)`` rows so every block is (8, 128)-tileable.
+
 Block shapes are MXU-aligned (multiples of 128 where the problem allows) and
 the K (reduction) grid axis is innermost so output blocks stay resident in
 VMEM across the reduction — output-stationary, like N-EUREKA's accumulators.
@@ -25,7 +33,6 @@ VMEM across the reduction — output-stationary, like N-EUREKA's accumulators.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,20 +40,49 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _unpack_block(wp: jax.Array, bits: int) -> jax.Array:
-    """uint8 carrier block (bn, bk/f) -> signed int8-valued int32 (bn, bk)."""
+def unpack_fields(wp: jax.Array, bits: int):
+    """uint8 carrier block (..., kf) -> ``8 // bits`` signed int32 planes.
+
+    Plane ``j`` holds the levels of reduction elements ``b*f + j`` for each
+    carrier byte ``b`` (the little-endian field order of
+    :func:`repro.core.packing.pack`), each the carrier's shape."""
+    w = wp.astype(jnp.int32)
     if bits == 8:
-        return wp.astype(jnp.int32) - 128
-    f = 8 // bits
-    shifts = (jnp.arange(f, dtype=jnp.uint32) * bits)
-    mask = jnp.uint32((1 << bits) - 1)
-    fields = (wp[..., None].astype(jnp.uint32) >> shifts) & mask
-    levels = fields.astype(jnp.int32) - (1 << (bits - 1))
-    bn, bkp, _ = levels.shape
-    return levels.reshape(bn, bkp * f)
+        return [w - 128]
+    mask = (1 << bits) - 1
+    off = 1 << (bits - 1)
+    return [((w >> (j * bits)) & mask) - off for j in range(8 // bits)]
 
 
-def _qmatmul_f32_kernel(x_ref, wp_ref, scale_ref, o_ref, *, bits: int, nk: int):
+def deinterleave(x: jax.Array, f: int) -> jax.Array:
+    """(M, K) -> (f, M, K/f) with plane j = x[:, j::f] (K a multiple of f)."""
+    m, k = x.shape
+    return x.reshape(m, k // f, f).transpose(2, 0, 1)
+
+
+def _dot_nt(a: jax.Array, b: jax.Array) -> jax.Array:
+    """a (m, k) . b (n, k)^T -> (m, n) f32."""
+    return jax.lax.dot_general(a.astype(jnp.float32), b.astype(jnp.float32),
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+# Largest reduction one integer dot may cover: Mosaic has no int32 matmul,
+# so uint8 x int8-level products run on the float MXU path, where both
+# operands are exact and a partial sum stays exact below 2**24
+# (512 * 255 * 128 < 2**24).
+INT_DOT_MAX_K = 512
+
+
+def int_dot_nt(a: jax.Array, b: jax.Array) -> jax.Array:
+    """Exact integer a (m, k) . b (n, k)^T -> (m, n) int32, for uint8
+    activations against signed 8-bit levels and k <= INT_DOT_MAX_K."""
+    assert a.shape[-1] <= INT_DOT_MAX_K, a.shape
+    return _dot_nt(a.astype(jnp.int32), b).astype(jnp.int32)
+
+
+def _qmatmul_f32_kernel(x_ref, wp_ref, scale_ref, o_ref, *, bits: int,
+                        nk: int):
     """out[m, n] = sum_k x[m, k] * unpack(wp)[n, k] * scale[n]  (f32 acc)."""
     k = pl.program_id(2)
 
@@ -54,20 +90,19 @@ def _qmatmul_f32_kernel(x_ref, wp_ref, scale_ref, o_ref, *, bits: int, nk: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)                    # (bm, bk)
-    w = _unpack_block(wp_ref[...], bits).astype(jnp.float32)   # (bn, bk)
-    o_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    acc = o_ref[...]
+    for j, w in enumerate(unpack_fields(wp_ref[...], bits)):
+        acc += _dot_nt(x_ref[j], w)
+    o_ref[...] = acc
 
     @pl.when(k == nk - 1)
     def _scale():
-        o_ref[...] = o_ref[...] * scale_ref[...][None, :]
+        o_ref[...] = o_ref[...] * scale_ref[...]
 
 
 def _qmatmul_f32_blockscale_kernel(x_ref, wp_ref, scale_ref, o_ref, *,
-                                   bits: int, block: int):
-    """out[m, n] = sum_k x[m, k] * unpack(wp)[n, k] * scale[n, k // block].
+                                   bits: int):
+    """out[m, n] = sum_k x[m, k] * unpack(wp)[n, k] * scale[k // block, n].
 
     The per-(channel, block) scales of the page wire encoding
     (core.quantize.quantize_blockwise) are applied to the unpacked levels
@@ -76,6 +111,12 @@ def _qmatmul_f32_blockscale_kernel(x_ref, wp_ref, scale_ref, o_ref, *,
     device format before compute.  Unlike the per-channel kernel there is
     no final scale step: each k-block is already fully scaled when it
     enters the MXU.
+
+    Carrier byte ``b`` of a block holds elements ``b*f .. b*f+f-1``, all in
+    scale group ``b // (block/f)``, so every field plane takes the same
+    per-byte scale: the plane is transposed to (bk/f, bn), split along
+    sublanes into (groups, block/f, bn), scaled by the (groups, 1, bn)
+    scale rows, and fed to the MXU as the right-hand operand.
     """
     k = pl.program_id(2)
 
@@ -83,14 +124,18 @@ def _qmatmul_f32_blockscale_kernel(x_ref, wp_ref, scale_ref, o_ref, *,
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...].astype(jnp.float32)                         # (bm, bk)
-    w = _unpack_block(wp_ref[...], bits).astype(jnp.float32)   # (bn, bk)
-    s = scale_ref[...]                                         # (bn, bk/block)
-    bn, bk = w.shape
-    w = (w.reshape(bn, bk // block, block) * s[:, :, None]).reshape(bn, bk)
-    o_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    s = scale_ref[...]                                   # (groups, bn)
+    groups, bn = s.shape
+    acc = o_ref[...]
+    for j, w in enumerate(unpack_fields(wp_ref[...], bits)):
+        wt = w.astype(jnp.float32).T                     # (bk/f, bn)
+        kf = wt.shape[0]
+        wt = (wt.reshape(groups, kf // groups, bn) * s[:, None, :]
+              ).reshape(kf, bn)
+        acc += jax.lax.dot_general(
+            x_ref[j].astype(jnp.float32), wt, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    o_ref[...] = acc
 
 
 def _qmatmul_int8_kernel(x_ref, wp_ref, mult_ref, bias_ref, o_ref, acc_ref,
@@ -107,17 +152,23 @@ def _qmatmul_int8_kernel(x_ref, wp_ref, mult_ref, bias_ref, o_ref, acc_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)                       # (bm, bk) uint8->i32
-    w = _unpack_block(wp_ref[...], bits)                   # (bn, bk) i32
-    acc_ref[...] += jax.lax.dot_general(
-        x, w, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    acc = acc_ref[...]
+    for j, w in enumerate(unpack_fields(wp_ref[...], bits)):
+        acc += int_dot_nt(x_ref[j], w)
+    acc_ref[...] = acc
 
     @pl.when(k == nk - 1)
     def _requant():
-        acc = acc_ref[...].astype(jnp.float32) * mult_ref[...][None, :]
-        acc = jnp.round(acc) + bias_ref[...][None, :].astype(jnp.float32)
-        o_ref[...] = jnp.clip(acc, 0.0, 255.0).astype(jnp.uint8)
+        o_ref[...] = requant_u8(acc_ref[...], mult_ref[...], bias_ref[...])
+
+
+def requant_u8(acc: jax.Array, mult: jax.Array, bias: jax.Array) -> jax.Array:
+    """NORMQUANT projection: int32 acc -> uint8 (float-rescale formulation).
+
+    The clipped value is cast through int32: Mosaic has no float -> uint8
+    conversion, and [0, 255] survives both casts exactly."""
+    y = jnp.round(acc.astype(jnp.float32) * mult) + bias.astype(jnp.float32)
+    return jnp.clip(y, 0.0, 255.0).astype(jnp.int32).astype(jnp.uint8)
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -127,6 +178,29 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+def _row_block(m: int, bm: int) -> int:
+    """Row block: ``bm``, shrunk to the 8-row tile above ``m`` so a decode
+    batch of a few rows is not padded out to a full prefill block."""
+    return min(bm, -(-m // 8) * 8)
+
+
+def _prep(x: jax.Array, packed: jax.Array, *, bits: int, bm: int, bn: int,
+          bk: int):
+    """Pad x / packed to whole blocks and de-interleave x by field."""
+    f = 8 // bits
+    assert bk % f == 0
+    bm = _row_block(x.shape[0], bm)
+    xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
+    wp = _pad_to(_pad_to(packed, 0, bn), 1, bk // f)
+    mp, kp = xp.shape
+    return deinterleave(xp, f), wp, bm, (mp // bm, wp.shape[0] // bn, kp // bk)
+
+
+def _row(v: jax.Array, dtype, n: int) -> jax.Array:
+    """Per-channel vector (N,) -> (1, N_padded) row."""
+    return _pad_to(v.astype(dtype), 0, n)[None, :]
 
 
 def qmatmul_f32(x: jax.Array, packed: jax.Array, scale: jax.Array, *,
@@ -139,31 +213,25 @@ def qmatmul_f32(x: jax.Array, packed: jax.Array, scale: jax.Array, *,
     factor so packed blocks stay byte-aligned (= MRAM-row aligned).
     """
     f = 8 // bits
-    assert bk % f == 0
     m, k = x.shape
     n = packed.shape[0]
     assert packed.shape[1] * f >= k_orig and k == k_orig
-
-    xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
-    wp = _pad_to(_pad_to(packed, 0, bn), 1, bk // f)
-    sp = _pad_to(scale.astype(jnp.float32), 0, bn)
-    mp, kp = xp.shape
-    np_, kpp = wp.shape
-    nk = kp // bk
-    grid = (mp // bm, np_ // bn, nk)
+    xd, wp, bm, grid = _prep(x, packed, bits=bits, bm=bm, bn=bn, bk=bk)
+    sp = _row(scale, jnp.float32, bn)
 
     out = pl.pallas_call(
-        functools.partial(_qmatmul_f32_kernel, bits=bits, nk=nk),
+        functools.partial(_qmatmul_f32_kernel, bits=bits, nk=grid[2]),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((f, bm, bk // f), lambda i, j, kk: (0, i, kk)),
             pl.BlockSpec((bn, bk // f), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((grid[0] * bm, wp.shape[0]),
+                                       jnp.float32),
         interpret=interpret,
-    )(xp, wp, sp)
+    )(xd, wp, sp)
     return out[:m, :n]
 
 
@@ -178,40 +246,36 @@ def qmatmul_f32_blockscale(x: jax.Array, packed: jax.Array,
     consumed directly — the At-MRAM expansion happens adjacent to the MXU
     with the *block* scale granularity of the page codec, so a cold page
     handed to compute run-quantized skips the host-side decode entirely.
-    ``block`` must divide ``bk`` so scale groups align with reduction
-    blocks; K tails shorter than a block are safe because the padded x
-    columns are zero.
+    ``block`` must divide ``bk`` (so scale groups align with reduction
+    blocks) and be a multiple of the packing factor; K tails shorter than
+    a block are safe because the padded x columns are zero.  The scales
+    enter the kernel transposed, (K/block, N), so a block of them is a
+    lane-dense (bk/block, bn) tile.
     """
     f = 8 // bits
-    assert bk % f == 0 and bk % block == 0
+    assert bk % block == 0 and block % f == 0
     m, k = x.shape
     n = packed.shape[0]
     assert packed.shape[1] * f >= k_orig and k == k_orig
     assert scales.shape == (n, -(-k_orig // block))
-
-    xp = _pad_to(_pad_to(x, 0, bm), 1, bk)
-    wp = _pad_to(_pad_to(packed, 0, bn), 1, bk // f)
-    kp = xp.shape[1]
-    sp = _pad_to(scales.astype(jnp.float32), 0, bn)
-    sp = jnp.pad(sp, ((0, 0), (0, kp // block - sp.shape[1])))
-    mp = xp.shape[0]
-    np_ = wp.shape[0]
-    nk = kp // bk
-    grid = (mp // bm, np_ // bn, nk)
+    xd, wp, bm, grid = _prep(x, packed, bits=bits, bm=bm, bn=bn, bk=bk)
+    kp = grid[2] * bk
+    st = _pad_to(scales.astype(jnp.float32), 0, bn).T
+    st = jnp.pad(st, ((0, kp // block - st.shape[0]), (0, 0)))
 
     out = pl.pallas_call(
-        functools.partial(_qmatmul_f32_blockscale_kernel, bits=bits,
-                          block=block),
+        functools.partial(_qmatmul_f32_blockscale_kernel, bits=bits),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((f, bm, bk // f), lambda i, j, kk: (0, i, kk)),
             pl.BlockSpec((bn, bk // f), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bn, bk // block), lambda i, j, kk: (j, kk)),
+            pl.BlockSpec((bk // block, bn), lambda i, j, kk: (kk, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((grid[0] * bm, wp.shape[0]),
+                                       jnp.float32),
         interpret=interpret,
-    )(xp, wp, sp)
+    )(xd, wp, st)
     return out[:m, :n]
 
 
@@ -223,33 +287,27 @@ def qmatmul_int8(x_q: jax.Array, packed: jax.Array, mult: jax.Array,
 
     ``mult`` is the folded float per-channel rescale (w_scale*in_scale/out_scale),
     ``bias`` the folded int32 per-channel bias (see core.quantize.fold_requant).
+    ``bk`` is capped at :data:`INT_DOT_MAX_K` so each block's dot is exact.
     """
     f = 8 // bits
-    assert bk % f == 0
+    bk = min(bk, INT_DOT_MAX_K)
     m, k = x_q.shape
     n = packed.shape[0]
-
-    xp = _pad_to(_pad_to(x_q, 0, bm), 1, bk)
-    wp = _pad_to(_pad_to(packed, 0, bn), 1, bk // f)
-    multp = _pad_to(mult.astype(jnp.float32), 0, bn)
-    biasp = _pad_to(bias.astype(jnp.int32), 0, bn)
-    mp, kp = xp.shape
-    np_ = wp.shape[0]
-    nk = kp // bk
-    grid = (mp // bm, np_ // bn, nk)
+    xd, wp, bm, grid = _prep(x_q, packed, bits=bits, bm=bm, bn=bn, bk=bk)
 
     out = pl.pallas_call(
-        functools.partial(_qmatmul_int8_kernel, bits=bits, nk=nk),
+        functools.partial(_qmatmul_int8_kernel, bits=bits, nk=grid[2]),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((f, bm, bk // f), lambda i, j, kk: (0, i, kk)),
             pl.BlockSpec((bn, bk // f), lambda i, j, kk: (j, kk)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((grid[0] * bm, wp.shape[0]),
+                                       jnp.uint8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(xp, wp, multp, biasp)
+    )(xd, wp, _row(mult, jnp.float32, bn), _row(bias, jnp.int32, bn))
     return out[:m, :n]

@@ -15,14 +15,20 @@ This kernel keeps the expansion entirely in VMEM:
 
 HBM traffic per token: x, dt, y (3·di) + B, C (2·N) bytes — the N-fold
 expansion never leaves VMEM, exactly the At-Memory discipline the paper
-applies to weights, applied here to the SSM state stream.  Per-chunk VMEM
-footprint: chunk x di_blk x N x 4 B (default 256x128x16 = 2 MiB).
+applies to weights, applied here to the SSM state stream.
+
+Inside a chunk the recurrence h_t = a_t * h_{t-1} + b_t runs once per state
+index n as a Hillis-Steele scan down the chunk's rows (log2(chunk) steps of
+a sublane roll plus a masked combine) — the shape Mosaic lowers, where the
+strided slices of ``lax.associative_scan`` do not.  The working set is a
+few chunk x di_blk f32 planes (256x128 = 128 KiB each), one state index
+at a time.  A enters transposed to (N, Di) and D as a (1, Di) row, so
+d_inner is the lane axis of every operand.
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -30,8 +36,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, h_ref, *,
-                 n_chunks: int):
+def _linear_scan(a: jax.Array, b: jax.Array):
+    """Inclusive scan of h_t = a_t * h_{t-1} + b_t down axis 0 (h_{-1} = 0):
+    returns (prod_{s<=t} a_s, h_t) — ``associative_scan``'s result for the
+    same combine, by doubling row shifts."""
+    t = a.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, a.shape, 0)
+    k = 1
+    while k < t:
+        live = row >= k
+        b = jnp.where(live, a * pltpu.roll(b, k, 0) + b, b)
+        a = jnp.where(live, a * pltpu.roll(a, k, 0), a)
+        k *= 2
+    return a, b
+
+
+def _scan_kernel(x_ref, dt_ref, at_ref, b_ref, c_ref, d_ref, o_ref, h_ref):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -40,24 +60,17 @@ def _scan_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, d_ref, o_ref, h_ref, *,
 
     x = x_ref[0].astype(jnp.float32)           # (T, dib)
     dt = dt_ref[0].astype(jnp.float32)         # (T, dib)
-    A = a_ref[...].astype(jnp.float32)         # (dib, N)
+    At = at_ref[...].astype(jnp.float32)       # (N, dib)
     B = b_ref[0].astype(jnp.float32)           # (T, N)
     C = c_ref[0].astype(jnp.float32)           # (T, N)
-
-    dA = jnp.exp(dt[:, :, None] * A[None])                   # (T, dib, N)
-    dBx = dt[:, :, None] * B[:, None, :] * x[:, :, None]     # (T, dib, N)
-
-    def comb(l, r):
-        la, lb = l
-        ra, rb = r
-        return la * ra, ra * lb + rb
-
-    aa, bb = jax.lax.associative_scan(comb, (dA, dBx), axis=0)
-    h_all = aa * h_ref[...][None] + bb                       # (T, dib, N)
-    h_ref[...] = h_all[-1]
-
-    y = jnp.sum(h_all * C[:, None, :], axis=-1)              # (T, dib)
-    y = y + x * d_ref[...][None, :]
+    u = dt * x
+    y = x * d_ref[...].astype(jnp.float32)     # the D skip term
+    for n in range(At.shape[0]):
+        aa, bb = _linear_scan(jnp.exp(dt * At[n:n + 1, :]),
+                              u * B[:, n:n + 1])
+        h = aa * h_ref[n:n + 1, :] + bb        # (T, dib)
+        h_ref[n:n + 1, :] = h[-1:, :]
+        y = y + h * C[:, n:n + 1]
     o_ref[0] = y.astype(o_ref.dtype)
 
 
@@ -88,21 +101,21 @@ def selective_scan_fused(x: jax.Array, dt: jax.Array, A: jax.Array,
     n_di = (di + dpad) // di_block
 
     out = pl.pallas_call(
-        functools.partial(_scan_kernel, n_chunks=n_chunks),
+        _scan_kernel,
         grid=(bsz, n_di, n_chunks),
         in_specs=[
             pl.BlockSpec((1, chunk, di_block), lambda b, d, c: (b, c, d)),
             pl.BlockSpec((1, chunk, di_block), lambda b, d, c: (b, c, d)),
-            pl.BlockSpec((di_block, n), lambda b, d, c: (d, 0)),
+            pl.BlockSpec((n, di_block), lambda b, d, c: (0, d)),
             pl.BlockSpec((1, chunk, n), lambda b, d, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, n), lambda b, d, c: (b, c, 0)),
-            pl.BlockSpec((di_block,), lambda b, d, c: (d,)),
+            pl.BlockSpec((1, di_block), lambda b, d, c: (0, d)),
         ],
         out_specs=pl.BlockSpec((1, chunk, di_block), lambda b, d, c: (b, c, d)),
         out_shape=jax.ShapeDtypeStruct((bsz, s + spad, di + dpad), x.dtype),
-        scratch_shapes=[pltpu.VMEM((di_block, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((n, di_block), jnp.float32)],
         interpret=interpret,
-    )(x, dt, A, B, C, D)
+    )(x, dt, A.T, B, C, D[None, :])
     return out[:, :s, :di]
 
 

@@ -6,26 +6,14 @@ which must set XLA_FLAGS before any jax import)."""
 
 from __future__ import annotations
 
+import math
+
 import jax
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh across jax versions: AxisType (explicit-sharding API)
-    only exists on newer jax; older releases default every axis to Auto
-    anyway, so omitting the argument is semantically identical there.
-    Releases predating jax.make_mesh itself fall back to constructing
-    jax.sharding.Mesh directly over the device grid."""
-    make = getattr(jax, "make_mesh", None)
-    if make is None:
-        import math
-        import numpy as np
-        n = math.prod(shape)
-        devs = np.asarray(jax.devices()[:n]).reshape(shape)
-        return jax.sharding.Mesh(devs, axes)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return make(shape, axes)
-    return make(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
@@ -36,25 +24,14 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")) -> jax.sharding.Mesh:
-    """Small mesh for CPU tests (requires xla_force_host_platform_device_count).
+    """Small mesh over the first ``prod(shape)`` devices: the chips of one
+    host, or CPU devices under xla_force_host_platform_device_count.
 
-    Degrades instead of raising when the host exposes fewer devices than
-    ``shape`` wants: each axis is clamped (left to right) to what remains
-    of ``jax.device_count()``, keeping the axis NAMES intact so sharding
-    rules still resolve — a 1-device host simply gets a (1, 1) mesh."""
-    import math
-    have = jax.device_count()
-    if math.prod(shape) > have:
-        import warnings
-        clamped = []
-        remaining = have
-        for s in shape:
-            use = min(s, remaining)
-            clamped.append(use)
-            remaining = max(1, remaining // use)
-        warnings.warn(
-            f"make_test_mesh: shape {tuple(shape)} wants "
-            f"{math.prod(shape)} devices but only {have} present; "
-            f"clamping to {tuple(clamped)}", stacklevel=2)
-        shape = tuple(clamped)
+    A shape that wants more devices than the host has is an error, never
+    a smaller mesh: a run that asked for N devices and got fewer would
+    report results it never produced."""
+    need, have = math.prod(shape), jax.device_count()
+    if need > have:
+        raise ValueError(f"mesh shape {tuple(shape)} needs {need} devices; "
+                         f"this host has {have}")
     return _make_mesh(shape, axes)

@@ -35,17 +35,19 @@ emitted as the ``repro.serving.metrics/v9`` JSON (stdout, and
 
 Mesh-sharded paging (ROADMAP 1(a); Siracusa's parallel memory-port
 concurrency): ``--mesh N`` (or ``NxM``) builds an in-process
-("data", "model") device mesh — run under
-``XLA_FLAGS=--xla_force_host_platform_device_count=K`` to get K host
-devices — and shards the paged store across the model axis: each device
+("data", "model") device mesh over the host's devices — the chips of a
+TPU host, or on a CPU host the K devices that
+``XLA_FLAGS=--xla_force_host_platform_device_count=K`` creates — and
+shards the paged store across the model axis: each device
 streams ONLY its shard's pages over its own link
 (:class:`repro.core.paging.ShardedPagedStore`), the tick's fence joins
 all the per-device streams, and the ``ShardedPoolLedger`` aggregates the
 per-device byte counters into one global ledger.  The greedy plan then
-charges sharded params at 1/N per device (``shard_factors``).  The
-verify leg re-serves single-device and asserts tokens BIT-EXACT plus the
-ledger identities: global counters equal the static per-device
-``kv_pass_counters`` prediction, global wire bytes equal the
+charges sharded params at 1/N per device (``shard_factors``).  A mesh
+the host cannot build, or one under which nothing would shard, is an
+error.  The verify leg re-serves single-device and asserts tokens
+BIT-EXACT plus the ledger identities: global counters equal the static
+per-device ``kv_pass_counters`` prediction, global wire bytes equal the
 single-device wire bytes, and every per-device link moves strictly
 fewer.
 
@@ -85,6 +87,7 @@ from repro.core.paging import (SharedPagePool, kv_pass_counters,
                                page_sizes, thread_packed)
 from repro.core.placement import (Placement, PlacementPlan, packed_sizes,
                                   plan_for_budget)
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer as tfm
 from repro.parallel.sharding import freeze_for_serving
 from repro.serving import (MultiScheduler, Request, Scheduler,
@@ -117,9 +120,8 @@ def _fetch_timeout_s(args):
 
 def _build_serve_mesh(spec):
     """--mesh's ("data", "model") mesh: "N" puts all N devices on the
-    model axis ((1, N)); "DxM" is an explicit (data, model) grid.  Built
-    through make_test_mesh, so a host with fewer devices clamps (with a
-    warning) instead of crashing."""
+    model axis ((1, N)); "DxM" is an explicit (data, model) grid.  A host
+    with fewer devices than the grid needs is an error."""
     if spec is None:
         return None
     from repro.launch.mesh import make_test_mesh
@@ -136,7 +138,10 @@ def _build_serve_mesh(spec):
         raise SystemExit(f"--mesh wants N or DxM, got {spec!r}")
     if any(d < 1 for d in shape):
         raise SystemExit(f"--mesh dims must be >= 1, got {spec!r}")
-    return make_test_mesh(shape, ("data", "model"))
+    try:
+        return make_test_mesh(shape, ("data", "model"))
+    except ValueError as e:
+        raise SystemExit(f"--mesh {spec}: {e}") from e
 
 
 def _mesh_shard_factors(packed, mesh):
@@ -368,6 +373,7 @@ def _main_multi(args):
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--models", default=None,
@@ -432,12 +438,13 @@ def main(argv=None):
                          "the model axis (or an explicit DxM grid), each "
                          "streaming only its shard's pages over its own "
                          "link, joined at the tick fence under one "
-                         "global byte ledger.  Run with XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=K; "
-                         "shapes clamp (with a warning) to the devices "
-                         "present.  The verify leg re-serves single-"
-                         "device and asserts tokens bit-exact plus the "
-                         "ledger/prediction identities")
+                         "global byte ledger.  On a CPU host, XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=K gives "
+                         "K devices.  A mesh the host cannot build, or "
+                         "one under which nothing shards, is an error.  "
+                         "The verify leg re-serves single-device and "
+                         "asserts tokens bit-exact plus the ledger/"
+                         "prediction identities")
     io = ap.add_mutually_exclusive_group()
     io.add_argument("--async-io", dest="async_io", action="store_true",
                     default=True,
@@ -501,9 +508,10 @@ def main(argv=None):
     packed = freeze_for_serving(params, bits=args.bits)
     mesh = _build_serve_mesh(args.mesh)
     shard_factors = _mesh_shard_factors(packed, mesh)
-    mesh_active = shard_factors is not None
-    if args.mesh is not None and not mesh_active:
-        print("--mesh: model axis clamped to 1 device; serving unsharded")
+    if mesh is not None and not shard_factors:
+        raise SystemExit(f"--mesh {args.mesh}: no parameter shards across "
+                         f"the model axis; use a model axis of >= 2 "
+                         f"devices")
     if args.budget_mb is not None:
         # greedy hot-set plan over exactly the packed leaves the serving
         # dispatch reads (PACKABLE matmul weights; embed/norms never page)
@@ -518,15 +526,16 @@ def main(argv=None):
     else:
         plan = PlacementPlan.uniform(args.scenario, bits=args.bits)
         paged = False
-    if mesh_active and not paged:
-        print("--mesh: nothing paged under this plan; serving unsharded")
-        mesh_active = False
+    if mesh is not None and not paged:
+        raise SystemExit(f"--mesh {args.mesh}: nothing is paged under this "
+                         f"plan, so the mesh would shard nothing; give a "
+                         f"--budget-mb below the packed weight bytes")
 
     tracer = Tracer() if args.trace_json else None
     done, sched, eng = _serve(cfg, packed, plan, args, paged,
                               kv_paged=args.kv_paged, tracer=tracer,
                               faults=_fault_plan(args),
-                              mesh=mesh if mesh_active else None)
+                              mesh=mesh)
     total_tokens = sum(len(r.generated) for r in done)
     place = ("mixed:" + "+".join(plan.scenarios_used())
              if not plan.is_uniform else plan.default.scenario)
@@ -550,7 +559,7 @@ def main(argv=None):
             print(f"page wire ({enc}): {wire} B streamed for {raw} B raw "
                   f"(x{raw / wire:.2f} compression vs fp32 dense)")
     mesh_doc = None
-    if paged and mesh_active:
+    if mesh is not None:
         # the ledger's determinism contract: runtime per-device counters,
         # summed, equal the static per-device kv_pass_counters replay
         pred = eng.pager.predict(eng.page_resident_slots)
@@ -625,7 +634,7 @@ def main(argv=None):
                                         paged=paged, async_io=False,
                                         kv_paged=args.kv_paged,
                                         faults=_fault_plan(args),
-                                        mesh=mesh if mesh_active else None)
+                                        mesh=mesh)
             sync_tokens = {r.uid: r.generated for r in sref}
             sync_ok = got == sync_tokens
             ctr_ok = (seng.swap_count == eng.swap_count
@@ -643,7 +652,7 @@ def main(argv=None):
                 seng.pager.close()
             if seng.kv_table is not None:
                 seng.kv_table.close()
-        if paged and mesh_active:
+        if mesh is not None:
             # the headline guarantee: the mesh changes WHERE pages live
             # and WHICH link moves them, never what the step computes —
             # the single-device paged run (same plan) must match token

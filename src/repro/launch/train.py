@@ -19,12 +19,14 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data import SyntheticLMDataset
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.steps import make_train_step, _init_fn
 from repro.optim import adamw, cosine_schedule
 from repro.runtime import Trainer, TrainerConfig, FailureInjector
 
 
 def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--steps", type=int, default=50)

@@ -1,15 +1,7 @@
 """Multi-device behaviour (sharding rules, compressed collectives, pipeline
 parallelism, elastic checkpoint restore) — each case runs in a subprocess
 with xla_force_host_platform_device_count so the main test process keeps
-its single CPU device.
-
-These passed again once launch/mesh.py stopped requiring
-``jax.sharding.AxisType`` (absent from older jax releases, where every
-mesh axis is Auto anyway); ``_mesh_supported`` keeps them a *named* skip
-— not a silent deselect — on environments where the forced-device
-subprocess cannot build a mesh at all, and
-``test_param_shardings_single_device_equivalence`` covers the sharding
-rules in-process on one device so the path is never untested."""
+its single CPU device."""
 
 import os
 import subprocess
@@ -19,17 +11,6 @@ import textwrap
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _mesh_supported() -> bool:
-    import jax
-    return hasattr(jax, "make_mesh")
-
-
-needs_mesh = pytest.mark.skipif(
-    not _mesh_supported(),
-    reason="this jax has no jax.make_mesh; the subprocess mesh tests "
-           "cannot run (single-device sharding equivalence still does)")
 
 
 def run_devices(code: str, n: int = 8, timeout: int = 420) -> str:
@@ -77,7 +58,6 @@ def test_param_shardings_single_device_equivalence():
 
 
 @pytest.mark.slow
-@needs_mesh
 def test_param_sharding_rules_all_archs():
     """Every leaf's PartitionSpec divides its dimensions, for all 10 archs,
     dense and packed trees, on a (2, 4) data x model mesh."""
@@ -106,7 +86,6 @@ def test_param_sharding_rules_all_archs():
 
 
 @pytest.mark.slow
-@needs_mesh
 def test_distributed_train_step_matches_single_device():
     """A jitted train step on a 2x2 mesh equals the single-device result."""
     run_devices("""
@@ -148,7 +127,6 @@ def test_distributed_train_step_matches_single_device():
 
 
 @pytest.mark.slow
-@needs_mesh
 def test_compressed_allreduce():
     run_devices("""
         import jax, jax.numpy as jnp, numpy as np
@@ -192,7 +170,6 @@ def test_compressed_allreduce():
 
 
 @pytest.mark.slow
-@needs_mesh
 def test_pipeline_parallel_equivalence():
     run_devices("""
         import jax, jax.numpy as jnp, numpy as np
@@ -243,16 +220,17 @@ def test_opt_state_shardings_keyed_by_path_not_shape():
 
 
 def test_make_test_mesh_clamps_to_available_devices():
-    """A shape wanting more devices than the host exposes degrades (with
-    a warning) instead of raising, keeping the axis NAMES intact."""
+    """A shape wanting more devices than the host exposes is an error —
+    never a smaller mesh that would pass for the one asked for."""
     import jax
     from repro.launch.mesh import make_test_mesh
 
     want = (jax.device_count() + 1, 2)
-    with pytest.warns(UserWarning, match="clamping"):
-        mesh = make_test_mesh(want, ("data", "model"))
+    with pytest.raises(ValueError, match="needs"):
+        make_test_mesh(want, ("data", "model"))
+    mesh = make_test_mesh((1, jax.device_count()), ("data", "model"))
     assert tuple(mesh.axis_names) == ("data", "model")
-    assert mesh.devices.size <= jax.device_count()
+    assert mesh.devices.size == jax.device_count()
 
 
 def test_plan_for_budget_charges_sharded_params_per_device():
@@ -319,7 +297,6 @@ _SHARDED_SERVE = """
 
 
 @pytest.mark.slow
-@needs_mesh
 def test_sharded_serving_bit_exact_fp_pages():
     """Mesh-sharded paged serving (fp pages) on a 1x4 mesh: serve.main's
     verify legs gate tokens bit-exact vs the single-device paged run
@@ -329,7 +306,6 @@ def test_sharded_serving_bit_exact_fp_pages():
 
 
 @pytest.mark.slow
-@needs_mesh
 def test_sharded_serving_bit_exact_int8_pages():
     """Same gates with int8-encoded page wire (--page-bits 8, the
     run-quantized identity): per-row scales slice along the shard axis
@@ -339,7 +315,6 @@ def test_sharded_serving_bit_exact_int8_pages():
 
 
 @pytest.mark.slow
-@needs_mesh
 def test_sharded_store_join_and_no_orphaned_pass():
     """ShardedPagedStore mechanics, below the engine: the joined fence
     reconstructs every sharded param's device bytes exactly, and an
@@ -365,6 +340,15 @@ def test_sharded_store_join_and_no_orphaned_pass():
         sps = ShardedPagedStore(store, page_bytes, mesh, plan=None,
                                 budget_bytes=1 << 22)
         assert sps.shard_axes, "smoke net must shard something"
+
+        # every link fetches onto its OWN device, not all onto device 0
+        assert len(set(sps.devices)) == 4
+        for sub in sps.stores:
+            with sub.begin_pass() as ps:
+                fetched = ps.fence()
+            for p in fetched.values():
+                assert p.packed.devices() == {sub.device}, sub.name
+                assert p.scale.devices() == {sub.device}, sub.name
 
         # a fenced pass joins the per-device fetches byte-exactly
         with sps.begin_pass() as ps1:
@@ -406,7 +390,6 @@ def test_sharded_store_join_and_no_orphaned_pass():
 
 
 @pytest.mark.slow
-@needs_mesh
 def test_elastic_checkpoint_restore_across_meshes(tmp_path):
     """Save sharded on a (4,2) mesh, restore onto (2,4) — elastic scaling."""
     run_devices(f"""
