@@ -1,0 +1,9 @@
+"""Page-worker time per tick spent checking page CRCs (the ``tobytes``
+copies and ``zlib.crc32`` over every streamed byte): the program's
+``paging.crc`` spans summed over the traced window, over its ticks."""
+
+from bench import program_spans
+
+
+def read(w):
+    return program_spans.per_tick_ms(w, "paging.crc")

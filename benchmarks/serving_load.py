@@ -65,10 +65,10 @@ admission counters — the bench-trajectory artefact for serving PRs.
 
 ``--trace-json PATH`` additionally records the whole bench — the solo
 leg, both tenants, and the continuous XR-gate leg — as one Chrome Trace
-Event JSON (per-tenant fence/admit/begin/compute spans, per-page I/O
-spans, preempt/restore/reject instants, and the predicted-vs-measured
-stall overlay); a disabled-``Tracer`` micro-gate holds the untraced
-hot-path hook under 5 us/call either way.
+Event JSON (per-tenant ``sched.*``/``engine.*`` spans, per-page
+``paging.fetch`` spans, preempt/restore/reject instants, and the
+measured stall split); a disabled-``Tracer`` micro-gate holds the
+untraced span hook under 5 us/call either way.
 
 Run:  PYTHONPATH=src python benchmarks/serving_load.py --smoke
 """
@@ -635,8 +635,8 @@ def main(argv=None):
         eng.kv_table.close()
 
     # disabled-tracer overhead gate: the tracer= hook must cost nothing
-    # when tracing is off — time the enabled=False no-op fast path the
-    # hot tick takes on every untraced run and hold it under 5 us/call
+    # when tracing is off — time the enabled=False path (the profiler-
+    # only span every untraced tick takes) and hold it under 5 us/call
     off = Tracer(enabled=False)
     reps = 10_000
     with Stopwatch() as sw:

@@ -141,8 +141,8 @@ def main():
     # and mid-request preemption, so an urgent wake-word request seizes a
     # slot THIS tick instead of queueing behind a long assistant prefill
     # record the whole tenancy run as a Chrome trace: one track per
-    # tenant (fence/admit/begin/compute spans + the predicted-stall
-    # overlay), one io track for page traffic, preempts as instants
+    # tenant (sched.* and engine.* spans, the measured stall split), one
+    # fetch track per page store, preempts as instants
     tracer = Tracer()
     ms = MultiScheduler(pool=pool, token_budget=24, preemptive=True,
                         tracer=tracer)

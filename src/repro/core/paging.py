@@ -694,6 +694,13 @@ def page_crc_of_buffers(wire: Sequence[Tuple[str, "HostParam", np.ndarray,
     return acc & 0xFFFFFFFF
 
 
+def _span(name: str, tracer: Any, track: str, **args):
+    """:func:`repro.serving.trace.span`, imported at first use: the
+    serving package imports this module."""
+    from repro.serving.trace import span
+    return span(name, tracer, track, **args)
+
+
 def retry_fetch(store: Any, idx: int, attempt_fn: Callable[[int], Any]) -> Any:
     """Run one logical page fetch under the store's retry policy.
 
@@ -828,41 +835,43 @@ class HostPagedStore:
         worker otherwise."""
         return self._pool if self.pool is None else self.pool._exec
 
-    def _fetch_page(self, idx: int) -> Dict[str, PackedParam]:
-        tr = self.tracer
-        t0 = tr.now() if tr is not None else 0.0
+    @property
+    def fetch_track(self) -> str:
+        """The Chrome track of this store's fetch spans: one per store,
+        since each store's fetches run on one worker thread."""
+        return f"io:{self.name}"
+
+    def _fetch_page(self, idx: int, pass_id: int = 0
+                    ) -> Dict[str, PackedParam]:
+        """One page fetch on the worker, as a ``paging.fetch`` span;
+        ``pass_id`` names the engine pass that submitted it (0: none)."""
         if self._closed:
             raise CancelledError(f"{self.name}: store closed before fetch "
                                  f"of page {idx} started")
-        if self.pool is not None:
-            cached = self.pool.lookup(self.name, idx)
-            if cached is not None:
-                if tr is not None:       # pool hit: no host->device swap
-                    tr.complete("page", tr.now() - t0, track="io",
-                                model=self.name, page=idx, pool_hit=True)
-                return cached
         page = self.pages[idx]
-        out = retry_fetch(self, idx,
-                          lambda attempt: self._fetch_page_once(idx, page,
-                                                                attempt))
-        if self._closed:
-            # close(wait=False) landed while this fetch was decoding:
-            # drop the page instead of installing into a closed store
-            raise CancelledError(f"{self.name}: store closed during fetch "
-                                 f"of page {idx}")
-        self.swap_count += 1
-        self.bytes_streamed_wire += page.wire_nbytes
-        self.bytes_streamed_raw += page.raw_nbytes
-        if self.pool is not None:
-            self.pool.admit(self.name, idx, page.nbytes, out,
-                            wire_nbytes=page.wire_nbytes,
-                            raw_nbytes=page.raw_nbytes)
-        if tr is not None:
-            tr.complete("page", tr.now() - t0, track="io", model=self.name,
-                        page=idx, nbytes=page.nbytes,
-                        wire_nbytes=page.wire_nbytes,
-                        encoding=page.encoding, pool_hit=False)
-        return out
+        cached = (self.pool.lookup(self.name, idx)
+                  if self.pool is not None else None)
+        with _span("paging.fetch", self.tracer, self.fetch_track,
+                   pass_id=pass_id, page=idx, wire_nbytes=page.wire_nbytes,
+                   pool_hit=cached is not None):
+            if cached is not None:       # pool hit: no host->device swap
+                return cached
+            out = retry_fetch(self, idx,
+                              lambda attempt: self._fetch_page_once(
+                                  idx, page, attempt))
+            if self._closed:
+                # close(wait=False) landed while this fetch was decoding:
+                # drop the page instead of installing into a closed store
+                raise CancelledError(f"{self.name}: store closed during "
+                                     f"fetch of page {idx}")
+            self.swap_count += 1
+            self.bytes_streamed_wire += page.wire_nbytes
+            self.bytes_streamed_raw += page.raw_nbytes
+            if self.pool is not None:
+                self.pool.admit(self.name, idx, page.nbytes, out,
+                                wire_nbytes=page.wire_nbytes,
+                                raw_nbytes=page.raw_nbytes)
+            return out
 
     def _fetch_page_once(self, idx: int, page: Page,
                          attempt: int) -> Dict[str, PackedParam]:
@@ -894,7 +903,8 @@ class HostPagedStore:
                         flipped, dtype=payload.dtype).reshape(payload.shape)
             wire.append((name, hp, payload, hp.scales))
         if page.crc32 is not None:
-            got = page_crc_of_buffers(wire)
+            with _span("paging.crc", self.tracer, self.fetch_track):
+                got = page_crc_of_buffers(wire)
             if got != page.crc32:
                 raise PageChecksumError(model=self.name, page=idx,
                                         expected=page.crc32, got=got)
@@ -911,20 +921,22 @@ class HostPagedStore:
                 # carrier's leading dims (stacked-layer params scan over
                 # the leading axis)
                 lead = hp.packed_shape[:-1]
-                out[name] = PackedParam(
-                    packed=jax.device_put(payload.reshape(*lead, -1),
-                                          self.device),
-                    scale=jax.device_put(scales.reshape(*lead, -1),
-                                         self.device),
-                    bits=hp.page_bits, orig_shape=hp.orig_shape)
+                with _span("paging.put", self.tracer, self.fetch_track):
+                    out[name] = PackedParam(
+                        packed=jax.device_put(payload.reshape(*lead, -1),
+                                              self.device),
+                        scale=jax.device_put(scales.reshape(*lead, -1),
+                                             self.device),
+                        bits=hp.page_bits, orig_shape=hp.orig_shape)
                 continue
             t_dec = time.perf_counter()
             packed, scale = hp.decode(payload=payload, scales=scales)
             self.decode_s += time.perf_counter() - t_dec
-            out[name] = PackedParam(
-                packed=jax.device_put(packed, self.device),
-                scale=jax.device_put(scale, self.device),
-                bits=hp.bits, orig_shape=hp.orig_shape)
+            with _span("paging.put", self.tracer, self.fetch_track):
+                out[name] = PackedParam(
+                    packed=jax.device_put(packed, self.device),
+                    scale=jax.device_put(scale, self.device),
+                    bits=hp.bits, orig_shape=hp.orig_shape)
         return out
 
     def template_view(self) -> Dict[str, PackedParam]:
@@ -961,7 +973,8 @@ class HostPagedStore:
         """
         return PageStream(self, resident_slots)
 
-    def begin_pass(self, resident_slots: int = 2) -> "AsyncPageStream":
+    def begin_pass(self, resident_slots: int = 2, pass_id: int = 0
+                   ) -> "AsyncPageStream":
         """Kick ONE full overlapped streaming pass and return immediately.
 
         The whole double-buffered fetch loop is submitted to the fetch
@@ -971,8 +984,8 @@ class HostPagedStore:
         at first use and splits the pass wall time into the *exposed*
         wait (time the caller actually blocked) and the *hidden* overlap
         — the §II-B2 proactive swap, realized across ticks instead of
-        across pages."""
-        return AsyncPageStream(self, resident_slots)
+        across pages.  Its fetch spans carry ``pass_id``."""
+        return AsyncPageStream(self, resident_slots, pass_id)
 
     def close(self, wait: bool = True):
         """Shut the prefetch worker down.  ``wait=True`` (default) blocks
@@ -1091,7 +1104,8 @@ class AsyncPageStream:
     leaking worker fetches or guard entries.
     """
 
-    def __init__(self, store: HostPagedStore, resident_slots: int = 2):
+    def __init__(self, store: HostPagedStore, resident_slots: int = 2,
+                 pass_id: int = 0):
         self._store = store
         self._result: Optional[Dict[str, PackedParam]] = None
         self._closed = False
@@ -1130,14 +1144,14 @@ class AsyncPageStream:
                 store.miss_count += 1        # demand miss (cold start)
                 self._futures.append(
                     (e.page, store._fetch_exec.submit(store._fetch_page,
-                                                      e.page)))
+                                                      e.page, pass_id)))
                 live.add(e.page)
             if e.prefetch_next is not None and e.prefetch_next not in live:
                 inflight.add(e.prefetch_next)
                 self._futures.append(
                     (e.prefetch_next,
                      store._fetch_exec.submit(store._fetch_page,
-                                              e.prefetch_next)))
+                                              e.prefetch_next, pass_id)))
             if e.evicts is not None:
                 live.discard(e.evicts)
         if pool is not None:
@@ -1578,9 +1592,10 @@ class ShardedPagedStore:
                 bits=parts[0].bits, orig_shape=tuple(orig))
         return view
 
-    def begin_pass(self, resident_slots: int = 2) -> "JoinedPageStream":
+    def begin_pass(self, resident_slots: int = 2, pass_id: int = 0
+                   ) -> "JoinedPageStream":
         self.ledger.pass_count += 1
-        return JoinedPageStream(self, resident_slots)
+        return JoinedPageStream(self, resident_slots, pass_id)
 
     def predict(self, resident_slots: int = 2) -> Dict[str, int]:
         return self.ledger.predict(resident_slots)
@@ -1620,7 +1635,7 @@ class JoinedPageStream:
     pool guard), so an early exit orphans no per-device pass."""
 
     def __init__(self, sharded: ShardedPagedStore,
-                 resident_slots: int = 2):
+                 resident_slots: int = 2, pass_id: int = 0):
         self._sharded = sharded
         self._result: Optional[Dict[str, PackedParam]] = None
         self._closed = False
@@ -1629,7 +1644,7 @@ class JoinedPageStream:
         self.exposed_s = 0.0
         self.hidden_s = 0.0
         self._t_begin = time.perf_counter()
-        self._streams = [s.begin_pass(resident_slots)
+        self._streams = [s.begin_pass(resident_slots, pass_id)
                          for s in sharded.stores]
 
     @property

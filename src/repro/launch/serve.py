@@ -458,10 +458,10 @@ def main(argv=None):
                     help="also write the metrics JSON to this path")
     ap.add_argument("--trace-json", default=None,
                     help="record the tick pipeline as a Chrome Trace "
-                         "Event JSON at this path (per-tenant fence/"
-                         "admit/begin/compute spans, per-page I/O spans, "
-                         "preempt/evict instants, and the predicted-vs-"
-                         "measured stall overlay); open in "
+                         "Event JSON at this path (per-tenant sched.* "
+                         "and engine.* spans, per-page paging.fetch "
+                         "spans, preempt/evict instants, and the "
+                         "measured stall split); open in "
                          "chrome://tracing or ui.perfetto.dev")
     ap.add_argument("--fault-seed", type=int, default=None,
                     help="chaos mode: run every page fetch under a "

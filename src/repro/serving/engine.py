@@ -49,7 +49,7 @@ import numpy as np
 from repro.core.placement import PlacementPlan, as_plan
 from repro.models import transformer as tfm
 from repro.models.config import ModelConfig
-from repro.serving.trace import now as _now
+from repro.serving.trace import now as _now, span
 
 
 def sample_token(logits: jax.Array, key: jax.Array, temperature: float = 1.0,
@@ -231,10 +231,13 @@ class ServingEngine:
         self.last_kv_overlap: Optional[Dict[str, float]] = None
         self._kv_synced = np.zeros(batch_slots, np.int64)  # blocks on host
 
-        # opt-in chrome-trace hook (set_tracer): None by default, so the
-        # un-traced fence/begin path pays one branch and nothing else
+        # opt-in Chrome-trace sink (set_tracer): None by default; the
+        # engine's spans also reach any recording profiler session
         self.tracer = None
         self.trace_track = "serve"
+        # id of the last page pass begun: each fetch span carries its
+        # pass's id, linking it to the begin that caused it
+        self.pass_id = 0
 
     # -- jitted bodies --------------------------------------------------------
     def _decode_impl(self, params, tokens, cache, pos_vec):
@@ -564,14 +567,15 @@ class ServingEngine:
         if self.kv_table is None:
             return
         block = self.kv_table.block_rows
-        for i, r in enumerate(self.slot_req):
-            if r is None:
-                continue
-            full = self._kv_valid(i) // block
-            if full > self._kv_synced[i]:
-                self.kv_table.writeback(i, int(self._kv_synced[i]), full,
-                                        self.cache["kv"])
-                self._kv_synced[i] = full
+        with span("engine.kv_sync", self.tracer, self.trace_track):
+            for i, r in enumerate(self.slot_req):
+                if r is None:
+                    continue
+                full = self._kv_valid(i) // block
+                if full > self._kv_synced[i]:
+                    self.kv_table.writeback(i, int(self._kv_synced[i]),
+                                            full, self.cache["kv"])
+                    self._kv_synced[i] = full
 
     def begin_tick_params(self) -> None:
         """Kick the overlapped host->device page stream for the NEXT
@@ -584,17 +588,23 @@ class ServingEngine:
         begin are demand-fetched at the fence)."""
         kicked = []
         if self.pager is not None and self._inflight_pass is None:
-            self._inflight_pass = self.pager.begin_pass(
-                self.page_resident_slots)
             kicked.append("weights")
         if self.kv_table is not None and self._inflight_kv is None:
+            kicked.append("kv")
+        if not kicked:
+            return
+        self.pass_id += 1
+        if "weights" in kicked:
+            self._inflight_pass = self.pager.begin_pass(
+                self.page_resident_slots, pass_id=self.pass_id)
+        if "kv" in kicked:
             self._kv_begun_gen = self._slot_gen.copy()
             self._inflight_kv = self.kv_table.begin_pass(
                 self._kv_full_blocks())
-            kicked.append("kv")
-        if kicked and self.tracer is not None:
+        if self.tracer is not None:
             self.tracer.instant("begin_pass", track=self.trace_track,
-                                streams="+".join(kicked))
+                                streams="+".join(kicked),
+                                pass_id=self.pass_id)
 
     def fence_tick_params(self, timeout_s: Optional[float] = None) -> Any:
         """The params tree for this tick, fencing at first use.
@@ -630,9 +640,11 @@ class ServingEngine:
         # with the passes still in flight (a fenced stream's result is
         # cached, so the retry re-joins it for free), and the accounting
         # below runs exactly once, on the tick that actually consumes
-        dev = ps.fence(timeout_s=timeout_s) if ps is not None else None
-        blocks = (ks.fence(self._kv_full_blocks(), timeout_s=timeout_s)
-                  if ks is not None else None)
+        with span("paging.wait", self.tracer, self.trace_track,
+                  pass_id=self.pass_id, demand=demand):
+            dev = ps.fence(timeout_s=timeout_s) if ps is not None else None
+            blocks = (ks.fence(self._kv_full_blocks(), timeout_s=timeout_s)
+                      if ks is not None else None)
         self._inflight_pass = None
         self._inflight_kv = None
         params = self.params
@@ -1029,6 +1041,13 @@ class ServingEngine:
     def _run_prefill_rows(self, params: Any, bucket: int, add_prefix: bool,
                           rows: List[Tuple[int, Request, int, int]],
                           k: int, started: List[Request]) -> None:
+        with span("engine.prefill", self.tracer, self.trace_track,
+                  bucket=bucket, rows=len(rows)):
+            self._prefill_rows(params, bucket, add_prefix, rows, k, started)
+
+    def _prefill_rows(self, params: Any, bucket: int, add_prefix: bool,
+                      rows: List[Tuple[int, Request, int, int]],
+                      k: int, started: List[Request]) -> None:
         kv_span = self._kv_span_for(bucket, rows)
         tokens = np.zeros((k, bucket), np.int32)
         slot_idx = np.zeros((k,), np.int32)
@@ -1057,8 +1076,10 @@ class ServingEngine:
             r.prefill_pos += n
             if r.prefill_pos < len(r.prompt):
                 continue                      # more chunks next tick
-            self.key, sub = jax.random.split(self.key)
-            tok = int(sample_token(logits[j, n - 1], sub, r.temperature))
+            with span("engine.sample", self.tracer, self.trace_track):
+                self.key, sub = jax.random.split(self.key)
+                tok = int(sample_token(logits[j, n - 1], sub,
+                                       r.temperature))
             r.generated.append(tok)
             r.first_token_s = _now()
             self.slot_pos[i] = len(r.prompt) + self.cfg.n_meta_tokens
@@ -1077,6 +1098,11 @@ class ServingEngine:
                   if r is not None and r.prefill_pos >= len(r.prompt)]
         if not active:
             return []
+        with span("engine.decode", self.tracer, self.trace_track,
+                  rows=len(active)):
+            return self._decode_rows(params, active)
+
+    def _decode_rows(self, params: Any, active: List[int]) -> List[Request]:
         tokens = np.zeros((self.slots, 1), np.int32)
         temps = np.zeros((self.slots,), np.float32)
         pos = np.full((self.slots,), self.max_len - 1, np.int32)
@@ -1104,8 +1130,9 @@ class ServingEngine:
             self.cache["ssm"] = jax.tree_util.tree_map(
                 lambda c, s: c.at[:, p_idx].set(s),
                 self.cache["ssm"], p_saved)
-        self.key, sub = jax.random.split(self.key)
-        toks = np.asarray(sample_token_batch(logits[:, -1], sub, temps))
+        with span("engine.sample", self.tracer, self.trace_track):
+            self.key, sub = jax.random.split(self.key)
+            toks = np.asarray(sample_token_batch(logits[:, -1], sub, temps))
         finished: List[Request] = []
         for i in active:
             req = self.slot_req[i]

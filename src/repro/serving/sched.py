@@ -77,10 +77,9 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.core.faults import PageFetchTimeout
 from repro.core.memsys import overlap_stall
-from repro.core.paging import pass_counters
 from repro.serving.engine import Request, ServingEngine, SlotCheckpoint
 from repro.serving.metrics import MetricsRecorder
-from repro.serving.trace import Tracer
+from repro.serving.trace import Tracer, span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,9 +182,8 @@ class Scheduler:
         self._compute_ema: Optional[float] = None
         self._swap_ema: Optional[float] = None
         self._est_seed_s = est_tick_s
-        # opt-in chrome-trace instrumentation: every hot-path hook guards
-        # on ``tracer is None`` (the default), so the un-traced tick pays
-        # one branch and allocates nothing
+        # opt-in Chrome-trace sink (None by default); the tick's spans
+        # also reach any recording profiler session (trace.span)
         self.tracer = tracer
         self.track = trace_track if trace_track is not None else "serve"
         if tracer is not None:
@@ -386,11 +384,7 @@ class Scheduler:
         return slot
 
     def _admit(self) -> None:
-        tr = self.tracer
-        if tr is None:
-            self._admit_impl()
-            return
-        with tr.span("admit", track=self.track):
+        with span("sched.admit", self.tracer, self.track):
             self._admit_impl()
 
     def _admit_impl(self) -> None:
@@ -470,14 +464,9 @@ class Scheduler:
         names the offending link's store (``<name>@dev<i>``)."""
         t0 = self.clock()
         self.metrics.start()                     # wall clock spans tick 1
-        tr = self.tracer
-        if tr is None:
+        with span("sched.fence", self.tracer, self.track, tick=self.ticks):
             params = self.engine.fence_tick_params(
                 timeout_s=self.fetch_timeout_s)
-        else:
-            with tr.span("fence", track=self.track, tick=self.ticks):
-                params = self.engine.fence_tick_params(
-                    timeout_s=self.fetch_timeout_s)
         return t0, params
 
     def tick_begin(self) -> None:
@@ -492,12 +481,10 @@ class Scheduler:
         else:
             more = self.engine.has_tick_after(self.prefill_chunk)
         if self.queue or self.preempted or more:
-            tr = self.tracer
-            if tr is None:
+            # pass_id: the id the engine gives the pass this call kicks
+            with span("sched.begin", self.tracer, self.track,
+                      pass_id=self.engine.pass_id + 1):
                 self.engine.begin_tick_params()
-            else:
-                with tr.span("begin", track=self.track):
-                    self.engine.begin_tick_params()
 
     def _compute_tick(self, params) -> List[Request]:
         """The engine-driving core of phase 3: planned prefills, one
@@ -517,47 +504,24 @@ class Scheduler:
 
     def _trace_tick(self, measured_exposed_s: float) -> None:
         """Accumulate this tick's predicted-vs-measured exposed-stall
-        drift (the metrics/v8 ``trace`` section) and, when tracing,
-        render the closed-form prediction on the ``<track> (predicted)``
-        overlay next to the measured fence spans."""
+        drift (the metrics/v8 ``trace`` section)."""
         eng = self.engine
         overlaps = [ov for ov in (eng.last_overlap, eng.last_kv_overlap)
                     if ov is not None]
         if not overlaps:
             return
-        pred_exposed = pred_hidden = swap = 0.0
-        for ov in overlaps:
-            st = overlap_stall(ov["swap_s"], ov["window_s"])
-            pred_exposed += st["exposed_s"]
-            pred_hidden += st["hidden_s"]
-            swap += ov["swap_s"]
-        self._pred_exposed_s += pred_exposed
+        self._pred_exposed_s += sum(
+            overlap_stall(ov["swap_s"], ov["window_s"])["exposed_s"]
+            for ov in overlaps)
         self._meas_exposed_s += measured_exposed_s
-        tr = self.tracer
-        if tr is None:
-            return
-        per_pass_swaps = (
-            pass_counters(len(eng.pager.pages),
-                          eng.page_resident_slots)["swaps"]
-            if eng.pager is not None else 0)
-        tr.complete("stall(pred)", pred_exposed,
-                    track=f"{self.track} (predicted)",
-                    predicted_exposed_ms=pred_exposed * 1e3,
-                    predicted_hidden_ms=pred_hidden * 1e3,
-                    measured_exposed_ms=measured_exposed_s * 1e3,
-                    swap_ms=swap * 1e3,
-                    predicted_swaps_per_pass=per_pass_swaps)
 
     def tick_compute(self, t0: float, params) -> List[Request]:
         """Phase 3: prefill per the tick plan (one chunk per slot when
         unbudgeted), one batched decode, retire + metrics — overlapping
         with the phase-2 stream."""
-        tr = self.tracer
-        if tr is None:
+        with span("sched.compute", self.tracer, self.track,
+                  tick=self.ticks):
             finished = self._compute_tick(params)
-        else:
-            with tr.span("compute", track=self.track, tick=self.ticks):
-                finished = self._compute_tick(params)
         now = self.clock()
         for req in finished:
             req.finish_s = now
@@ -610,15 +574,17 @@ class Scheduler:
         With a ``fetch_timeout_s``, a fence that exceeds the deadline
         defers the whole tick (empty return) instead of blocking: the
         pass resumes at the next tick's fence."""
-        try:
-            t0, params = self.tick_fence()
-        except PageFetchTimeout as e:
-            self.defer_tick(e)
-            return []
-        self._admit()
-        self._tick_plan = self._plan_tick()
-        self.tick_begin()
-        return self.tick_compute(t0, params)
+        with span("sched.tick", self.tracer, self.track, tick=self.ticks):
+            try:
+                t0, params = self.tick_fence()
+            except PageFetchTimeout as e:
+                self.defer_tick(e)
+                return []
+            self._admit()
+            with span("sched.plan", self.tracer, self.track):
+                self._tick_plan = self._plan_tick()
+            self.tick_begin()
+            return self.tick_compute(t0, params)
 
     # -- loops ----------------------------------------------------------------
     @property

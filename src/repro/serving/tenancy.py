@@ -63,7 +63,7 @@ from repro.core.paging import SharedPagePool
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.metrics import multi_summary
 from repro.serving.sched import Scheduler, StreamSpec
-from repro.serving.trace import Tracer
+from repro.serving.trace import Tracer, span
 
 
 class MultiScheduler:
@@ -310,12 +310,8 @@ class MultiScheduler:
         order of the synchronous loop, which is what keeps the shared
         pool's counters on the static ``shared_pass_counters``
         prediction.  Returns {model: requests finished this tick}."""
-        tr = self.tracer
-        if tr is None:
+        with span("sched.admit", self.tracer, "scheduler", tick=self.ticks):
             self._admit_global()
-        else:
-            with tr.span("admit", track="scheduler", tick=self.ticks):
-                self._admit_global()
         active = [(name, sched) for name, sched in self.models.items()
                   if sched.pending]
         fenced = []

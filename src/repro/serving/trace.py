@@ -1,45 +1,51 @@
-"""Chrome-trace span instrumentation for the serving tick pipeline.
+"""Span instrumentation for the serving tick pipeline, with two sinks.
 
 The serving stack software-pipelines weight and KV paging behind compute
 and preempts mid-request under 10-20 ms XR deadlines, but aggregate
 counters (``metrics.py``) cannot show *when* a fence blocked, which page
 fetch straddled a tick boundary, or whom a preemption evicted.  This
-module is the timeline view: a zero-dependency span tracer whose output
-is Chrome Trace Event Format JSON — load it in ``chrome://tracing`` or
-https://ui.perfetto.dev and every tick's fence -> admit -> begin ->
-compute phases, every per-page host->device fetch, every preemption /
-admission verdict, and the closed-form stall *prediction*
-(:func:`repro.core.memsys.overlap_stall`) render as parallel tracks.
+module is the timeline view.  One :func:`span` call site feeds both sinks:
+
+  * **the profiler** — whenever a ``jax.profiler`` session is recording
+    (``jax.profiler.trace``, ``start_trace``, a profiler server), every
+    span opens a ``jax.profiler.TraceAnnotation`` of the same name and
+    arguments, so it lands in the ``.xplane.pb`` on the same clock as the
+    device's operations, on the thread that ran it;
+  * **Chrome Trace Event JSON** — when a :class:`Tracer` is attached
+    (``serve --trace-json``, :class:`~repro.serving.tenancy.MultiScheduler`,
+    :class:`~repro.runtime.monitor.StragglerMonitor`), it also records a
+    ``B``/``E`` pair on its track; load the file in ``chrome://tracing``
+    or https://ui.perfetto.dev.
+
+Span names are ``<layer>.<phase>``: ``sched.tick`` holding
+``sched.fence`` (-> ``paging.wait``), ``sched.admit``, ``sched.plan``,
+``sched.begin`` and ``sched.compute`` (-> ``engine.prefill``,
+``engine.decode``, each holding ``engine.sample``, and
+``engine.kv_sync``); on the page worker thread ``paging.fetch`` per page
+holding ``paging.crc`` and ``paging.put``.  ``pass_id`` links a fetch to
+the ``sched.begin`` (or demand-begun ``paging.wait``) that caused it.
 
 Design constraints, in order:
 
-  * **no-op when absent** — every instrumented hot path guards on
-    ``tracer is None`` (the default), so the un-traced tick loop pays
-    one attribute load + branch and allocates nothing;
+  * **no-op when absent** — with no profiler session and no tracer,
+    :func:`span` makes one ``TraceAnnotation.is_enabled()`` call and
+    returns the shared null span: no allocation, no event;
   * **thread-safe** — page fetches run on the pool's serialized worker
     thread while the scheduler emits from the tick loop; one lock
-    serializes event append and track registration;
-  * **monotonic clock** — timestamps come from ``time.perf_counter``
-    (via :data:`now`, the one canonical timestamp helper the serving
-    stack shares) and are exported as microseconds relative to tracer
-    construction;
-  * **zero dependencies** — stdlib only, importable from ``core``
-    without pulling the serving package in.
+    serializes Chrome event append and track registration, and each
+    worker's live spans sit on a track of their own;
+  * **monotonic clock** — Chrome timestamps come from
+    ``time.perf_counter`` (via :data:`now`, the one canonical timestamp
+    helper the serving stack shares) and are exported as microseconds
+    relative to tracer construction.
 
-Event kinds map 1:1 onto the Trace Event Format: ``span`` emits ``B``/
-``E`` duration pairs (single-emitter tracks: scheduler phases),
-``complete`` emits one ``X`` event with an explicit duration (worker-
-thread page fetches, the retro-dated stall spans), ``instant`` emits
-``i`` (admission verdicts, preemptions, evictions), ``counter`` emits
-``C`` (pool occupancy).  ``track`` names become ``thread_name``
-metadata, one tid per track.
-
-Since the encoded-pages refactor the ``io`` track splits its byte
-arguments wire-vs-device: a swap's ``page`` span carries ``nbytes``
-(decoded device footprint), ``wire_nbytes`` (what the link moved:
-encoded payload + scales) and ``encoding``; the ``pool_bytes`` counter
-samples both ``bytes`` (device occupancy, what the budget charges) and
-``wire_bytes`` as parallel series.
+Chrome event kinds map 1:1 onto the Trace Event Format: ``span`` emits
+``B``/``E`` duration pairs (single-emitter tracks), ``complete`` emits
+one ``X`` event with an explicit duration (KV block fetches and the
+retro-dated ``hidden:*``/``exposed:*`` stall bars, Chrome-only),
+``instant`` emits ``i`` (admission verdicts, preemptions, evictions),
+``counter`` emits ``C`` (pool occupancy).  ``track`` names become
+``thread_name`` metadata, one tid per track.
 
 :func:`validate` asserts structural validity (every ``B`` has a
 matching ``E``, ``B``/``E``/``i`` timestamps monotonic per track,
@@ -55,6 +61,8 @@ import json
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 #: The canonical monotonic timestamp source for the serving stack.
 #: ``engine``/``sched``/``monitor`` stamp through this single alias
@@ -110,14 +118,32 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: True while a ``jax.profiler`` session records.
+_profiling: Callable[[], bool] = TraceAnnotation.is_enabled
+
+
+def span(name: str, tracer: Optional["Tracer"] = None, track: str = "main",
+         **args):
+    """A ``with``-able span called ``name`` with ``args``: a profiler
+    ``TraceAnnotation`` while a profiler session records, plus a Chrome
+    ``B``/``E`` pair on ``track`` when ``tracer`` is given.  With neither,
+    the shared null span."""
+    if tracer is not None:
+        return tracer.span(name, track=track, **args)
+    if not _profiling():
+        return _NULL_SPAN
+    return TraceAnnotation(name, **args)
+
 
 class Span:
-    """One live ``B``/``E`` pair.  After ``__exit__``, :attr:`dur_s`
-    holds the measured duration — consumers like
+    """One live ``B``/``E`` pair, also a profiler annotation while a
+    profiler session records.  After ``__exit__``, :attr:`dur_s` holds
+    the measured duration — consumers like
     :class:`~repro.runtime.monitor.StragglerMonitor` read their step
     time from the span instead of keeping their own bracketing."""
 
-    __slots__ = ("_tracer", "name", "track", "args", "t0_s", "dur_s")
+    __slots__ = ("_tracer", "name", "track", "args", "t0_s", "dur_s",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, track: str,
                  args: Optional[Dict[str, Any]]):
@@ -127,14 +153,22 @@ class Span:
         self.args = args
         self.t0_s = 0.0
         self.dur_s = 0.0
+        self._annotation = None
 
     def __enter__(self) -> "Span":
         self.t0_s = self._tracer.clock()
         self._tracer._emit("B", self.name, self.track, self.t0_s,
                            self.args)
+        if _profiling():
+            self._annotation = TraceAnnotation(self.name,
+                                               **(self.args or {}))
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         t1 = self._tracer.clock()
         self.dur_s = t1 - self.t0_s
         self._tracer._emit("E", self.name, self.track, t1, None)
@@ -147,10 +181,8 @@ class Tracer:
     ``clock`` must be monotonic (default :data:`now` ==
     ``time.perf_counter``); timestamps are exported in microseconds
     relative to construction.  ``enabled=False`` turns every emit
-    method into an immediate return and :meth:`span` into the shared
-    no-allocation null span — the programmatic off switch (the serving
-    hot paths additionally guard on ``tracer is None`` so the default
-    un-traced run never even reaches these methods)."""
+    method into an immediate return and :meth:`span` into the
+    profiler-only :func:`span` — the Chrome sink's off switch."""
 
     def __init__(self, clock: Callable[[], float] = now,
                  enabled: bool = True, pid: int = 0):
@@ -194,11 +226,12 @@ class Tracer:
     # -- emit API -------------------------------------------------------------
     def span(self, name: str, track: str = "main", **args):
         """A ``with``-able duration span on ``track``.  Enter emits
-        ``B``, exit emits ``E`` and records ``dur_s``.  Spans on one
-        track must nest (single-emitter tracks); concurrent emitters
-        should use :meth:`complete` instead."""
+        ``B``, exit emits ``E`` and records ``dur_s``; a recording
+        profiler session sees the same span.  Spans on one track must
+        nest (single-emitter tracks): each emitting thread takes a track
+        of its own."""
         if not self.enabled:
-            return _NULL_SPAN
+            return span(name, **args)
         return Span(self, name, track, args or None)
 
     def instant(self, name: str, track: str = "main", **args) -> None:
@@ -219,9 +252,9 @@ class Tracer:
                  end_offset_s: float = 0.0, **args) -> None:
         """One already-finished span (``X``) ending ``end_offset_s``
         seconds before *now* with duration ``dur_s`` — the shape for
-        worker-thread page fetches (measured locally, emitted once
-        done) and for retro-dating stall spans whose window closed
-        before the accounting ran."""
+        KV block fetches (measured locally, emitted once done) and for
+        retro-dating stall spans whose window closed before the
+        accounting ran.  Chrome-only: the profiler sees live spans."""
         if not self.enabled:
             return
         t1 = self.clock() - end_offset_s
