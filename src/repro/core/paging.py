@@ -28,6 +28,8 @@ Two streaming modes share one schedule and one set of counters:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import os
 import threading
 import time
 import zlib
@@ -50,6 +52,64 @@ from repro.core.weight_store import WeightStore, PackedParam, SIRACUSA_MRAM_BYTE
 
 # Scale-group width of the intN page wire codec (weights per f32 scale).
 PAGE_ENC_BLOCK = quantize.PAGE_SCALE_BLOCK
+
+# Chunk size of the wire checksum (:func:`wire_checksums`).  Fixed, not
+# derived from the thread count, so a checksum is the same on every host.
+CRC_CHUNK_BYTES = 16 << 20
+
+_crc_pool: Optional[ThreadPoolExecutor] = None
+_crc_pool_lock = threading.Lock()
+
+
+def _crc_executor() -> ThreadPoolExecutor:
+    """The one pool that checks large wire buffers chunk by chunk,
+    created at first use and shared by every store."""
+    global _crc_pool
+    with _crc_pool_lock:
+        if _crc_pool is None:
+            _crc_pool = ThreadPoolExecutor(
+                max_workers=min(4, max(1, (os.cpu_count() or 1) // 2)),
+                thread_name_prefix="page-crc")
+        return _crc_pool
+
+
+def _wire_bytes(buf: np.ndarray) -> np.ndarray:
+    """``buf``'s bytes as a flat uint8 view (a copy only when ``buf`` is
+    not C-contiguous, which ``zlib.crc32`` could not read in place)."""
+    return np.ascontiguousarray(buf).reshape(-1).view(np.uint8)
+
+
+def _pooled_crc_chunks(nbytes: int) -> int:
+    """Chunks a buffer of ``nbytes`` sends to the pool: none when it fits
+    one chunk (checked inline on the calling thread)."""
+    return -(-nbytes // CRC_CHUNK_BYTES) if nbytes > CRC_CHUNK_BYTES else 0
+
+
+def wire_checksums(params: Sequence[Tuple[np.ndarray, np.ndarray]]
+                   ) -> List[int]:
+    """The wire checksum of each ``(payload, scales)`` pair: the CRC32
+    over the 4-byte little-endian CRC32s of the consecutive
+    :data:`CRC_CHUNK_BYTES` chunks of the payload, then of the scales.
+
+    Every byte is read in place (``zlib.crc32`` releases the GIL on
+    large buffers).  A buffer larger than one chunk has its chunks
+    checked on the shared pool; the rest are checked on the calling
+    thread while the pool runs."""
+    size = CRC_CHUNK_BYTES
+    pending: List[List[Callable[[], int]]] = []   # per param, per chunk
+    for payload, scales in params:
+        crcs: List[Callable[[], int]] = []
+        for buf in (_wire_bytes(payload), _wire_bytes(scales)):
+            if _pooled_crc_chunks(buf.nbytes):
+                crcs += [_crc_executor().submit(zlib.crc32,
+                                                buf[i:i + size]).result
+                         for i in range(0, buf.nbytes, size)]
+            elif buf.nbytes:
+                crcs.append(functools.partial(zlib.crc32, buf))
+        pending.append(crcs)
+    # every pooled chunk is submitted before the first inline CRC runs
+    return [zlib.crc32(b"".join(crc().to_bytes(4, "little") for crc in crcs))
+            for crcs in pending]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -595,8 +655,9 @@ class HostParam:
     page_bits: Optional[int]          # wire bits (None = fp/verbatim)
     payload: np.ndarray
     scales: np.ndarray
-    # CRC32 over (payload, scales) bytes — the param's share of its page's
-    # wire checksum (:func:`page_crc`); stamped by encode_host_param
+    # chunked CRC32 over the (payload, scales) bytes (:func:`wire_checksums`)
+    # — the param's share of its page's checksum (:func:`page_crc`);
+    # stamped by encode_host_param, held in memory only
     crc32: Optional[int] = None
 
     @property
@@ -613,13 +674,12 @@ class HostParam:
 
     def wire_crc(self, payload: Optional[np.ndarray] = None,
                  scales: Optional[np.ndarray] = None) -> int:
-        """CRC32 of the wire image — of the stored buffers, or of the
-        buffers a fetch actually received (to verify before decode)."""
+        """Chunked CRC32 of the wire image (:func:`wire_checksums`) — of the
+        stored buffers, or of the buffers a fetch actually received (to
+        verify before decode)."""
         payload = self.payload if payload is None else payload
         scales = self.scales if scales is None else scales
-        crc = zlib.crc32(np.ascontiguousarray(payload).tobytes())
-        crc = zlib.crc32(np.ascontiguousarray(scales).tobytes(), crc)
-        return crc & 0xFFFFFFFF
+        return wire_checksums([(payload, scales)])[0]
 
     def decode(self, payload: Optional[np.ndarray] = None,
                scales: Optional[np.ndarray] = None
@@ -686,10 +746,11 @@ def page_roundtrip_param(p: PackedParam, page_bits: Optional[int]
 def page_crc_of_buffers(wire: Sequence[Tuple[str, "HostParam", np.ndarray,
                                              np.ndarray]]) -> int:
     """Page CRC recomputed from the buffers a fetch actually received —
-    the verify-side counterpart of :func:`page_crc`."""
+    the verify-side counterpart of :func:`page_crc`.  The chunks of every
+    param of the page go to the pool together."""
     acc = 0
-    for _name, hp, payload, scales in wire:
-        c = hp.wire_crc(payload=payload, scales=scales)
+    for c in wire_checksums([(payload, scales)
+                             for _name, _hp, payload, scales in wire]):
         acc = zlib.crc32(c.to_bytes(4, "little"), acc)
     return acc & 0xFFFFFFFF
 
@@ -903,7 +964,11 @@ class HostPagedStore:
                         flipped, dtype=payload.dtype).reshape(payload.shape)
             wire.append((name, hp, payload, hp.scales))
         if page.crc32 is not None:
-            with _span("paging.crc", self.tracer, self.fetch_track):
+            sizes = [int(b.nbytes) for _n, _hp, payload, scales in wire
+                     for b in (payload, scales)]
+            with _span("paging.crc", self.tracer, self.fetch_track,
+                       nbytes=sum(sizes),
+                       chunks=sum(_pooled_crc_chunks(n) for n in sizes)):
                 got = page_crc_of_buffers(wire)
             if got != page.crc32:
                 raise PageChecksumError(model=self.name, page=idx,

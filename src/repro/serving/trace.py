@@ -22,8 +22,9 @@ Span names are ``<layer>.<phase>``: ``sched.tick`` holding
 ``sched.begin`` and ``sched.compute`` (-> ``engine.prefill``,
 ``engine.decode``, each holding ``engine.sample``, and
 ``engine.kv_sync``); on the page worker thread ``paging.fetch`` per page
-holding ``paging.crc`` and ``paging.put``.  ``pass_id`` links a fetch to
-the ``sched.begin`` (or demand-begun ``paging.wait``) that caused it.
+holding ``paging.crc`` (``nbytes``, ``chunks``) and ``paging.put``.
+``pass_id`` links a fetch to the ``sched.begin`` (or demand-begun
+``paging.wait``) that caused it.
 
 Design constraints, in order:
 

@@ -266,7 +266,8 @@ def test_profiler_sees_the_program_spans(packed, rng, tmp_path):
     program's spans: per tick one ``sched.tick`` holding ``sched.fence``
     and ``sched.admit``; one ``engine.decode`` per decode step, each
     holding one ``engine.sample``; one ``paging.fetch`` per swapped page
-    on the page worker's thread, each holding its ``paging.crc`` and
+    on the page worker's thread, each holding its ``paging.crc`` (with the
+    bytes it checked and the chunks it sent to the pool) and
     ``paging.put``, and each naming the pass that asked for it."""
     from bench import program_spans, xplane
 
@@ -316,7 +317,11 @@ def test_profiler_sees_the_program_spans(packed, rng, tmp_path):
     assert len(fetches) == eng.swap_count > 0
     for f in fetches:
         assert f.thread != main
-        assert sum(1 for sp in t.named("paging.crc") if f.holds(sp)) == 1
+        crcs = [sp for sp in t.named("paging.crc") if f.holds(sp)]
+        assert len(crcs) == 1
+        assert crcs[0].thread == f.thread
+        assert crcs[0].args["nbytes"] == f.args["wire_nbytes"]
+        assert crcs[0].args["chunks"] >= 0
         assert sum(1 for sp in t.named("paging.put") if f.holds(sp)) >= 1
     named = {sp.args["pass_id"] for sp in t.named("sched.begin")}
     named |= {sp.args["pass_id"] for sp in t.named("paging.wait")
