@@ -22,13 +22,9 @@ is scored and judged exactly as a served token is.
 
 from __future__ import annotations
 
-import importlib
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
-
-from bench.model import Dims
-from bench.weights import make_weights
 
 ROW_BLOCK = 128
 # the reference module's lower precisions: "int8" (int8 activations into
@@ -66,7 +62,7 @@ def sample(recs, conf: Dict, seed: int) -> List:
     return picked[:c["max_sequences"]]
 
 
-def _batch(d: Dims, picked, n_rows: int):
+def _batch(d, picked, n_rows: int):
     """Tokens (prompt + served tokens but the last) and targets (the
     served tokens at the positions that produced them), padded to
     ``max_len`` rounded up to a whole number of 128-row blocks."""
@@ -84,10 +80,6 @@ def _batch(d: Dims, picked, n_rows: int):
     return tokens, targets, mask
 
 
-def reference(conf: Dict):
-    return importlib.import_module(f"bench.references.{conf['reference']}")
-
-
 def _numbers(best, at, mask) -> Dict[str, float]:
     gap = (np.asarray(best) - np.asarray(at))[mask]
     return dict(mean_logit_gap=float(gap.mean()),
@@ -95,11 +87,14 @@ def _numbers(best, at, mask) -> Dict[str, float]:
                 disagree=float(np.mean(gap > 0)))
 
 
-def check(d: Dims, conf: Dict, seed: int, recs,
+def check(family, ref, d, conf: Dict, seed: int, recs,
           controls: Sequence[str] = ()) -> Dict:
     """The comparison's numbers for the served tokens (``program``) and
     for each control named, each a dict of ``mean_logit_gap`` (the number
-    compared), ``max_logit_gap`` and ``disagree``; None with no sample."""
+    compared), ``max_logit_gap`` and ``disagree``; None with no sample.
+    ``ref`` is the configuration's reference module
+    (``references/<conf["reference"]>.py``); it scores the weights that
+    ``family.make_weights`` makes again from the seed."""
     picked = sample(recs, conf, seed)
     out = dict(sequences=len(picked),
                tokens=sum(len(r.req.generated) for r in picked),
@@ -107,8 +102,7 @@ def check(d: Dims, conf: Dict, seed: int, recs,
                program=None)
     if not picked:
         return out
-    ref = reference(conf)
-    weights = make_weights(d, seed)
+    weights = family.make_weights(d, seed)
     tokens, targets, mask = _batch(d, picked,
                                    conf["correct"]["max_sequences"])
     best, _a, at = ref.score(d, weights, tokens, targets)

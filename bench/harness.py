@@ -1,7 +1,8 @@
 """Build one cell's serving objects, warm them up, and drive the window.
 
 The cell is served the way ``launch/serve.py`` serves a model: the packed
-int8 tree, a placement plan (all resident, or ``plan_for_budget`` at the
+tree that the configuration's family module (``bench/families/``) makes
+from the seed, a placement plan (all resident, or ``plan_for_budget`` at the
 config's resident share with the launcher's hot and cold placements and
 the cold weights streamed from the host every tick), a ``ServingEngine``
 and a ``Scheduler`` with the mix's streams.  The window drives
@@ -13,20 +14,14 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import jax
 import numpy as np
 
 from bench import traffic
-from bench.model import Dims
-from bench.weights import make_weights
 
 clock = time.perf_counter
-
-
-def next_pow2(n: int) -> int:
-    return 1 << max(int(n) - 1, 0).bit_length()
 
 
 @dataclasses.dataclass
@@ -53,8 +48,10 @@ class ReqRec:
 
 @dataclasses.dataclass
 class Window:
-    """What a window left behind for the metric readers."""
-    dims: Dims
+    """What a window left behind for the metric readers.  ``family`` is
+    the cell's family module, whose counts read ``dims``."""
+    family: Any
+    dims: Any
     peaks: Dict
     window_s: float
     requests: List[ReqRec]
@@ -94,44 +91,14 @@ class Compiles:
             self.cache_hits += 1
 
 
-def prefill_shapes(mix: Dict, d: Dims) -> List[tuple]:
-    """Every (bucket, kv_span) prefill program the mix can reach: chunks
-    of n tokens run in the bucket next_pow2(n) and attend the cache up to
-    next_pow2(insert position + bucket), capped at ``max_len``."""
-    sc = mix["scheduler"]
-    chunk = next_pow2(sc["prefill_chunk"])
-    pmin, pmax = traffic.length_range(mix, "prompt")
-    budgeted = sc.get("token_budget") is not None
-    shapes = set()
-    for n in range(1, min(chunk, pmax) + 1):
-        b = next_pow2(n)
-        if budgeted:
-            positions = range(0, pmax - n + 1)
-        else:
-            positions = range(0, pmax - n + 1, chunk)
-        for pos in positions:
-            if pos == 0 and not budgeted and n < min(pmin, chunk):
-                continue
-            shapes.add((b, min(d.max_len, next_pow2(pos + b))))
-    return sorted(shapes)
-
-
-def to_model_config(d: Dims):
-    from repro.models.config import ModelConfig
-    return ModelConfig(
-        name=d.name, family="dense", n_layers=d.n_layers,
-        d_model=d.d_model, n_heads=d.n_heads, n_kv_heads=d.n_kv_heads,
-        head_dim=d.head_dim, d_ff=d.d_ff, vocab_size=d.vocab,
-        qk_norm=d.qk_norm, qkv_bias=False, rope_theta=d.rope_theta,
-        mlp_act="swiglu", norm_type=d.norm, tie_embeddings=True,
-        weight_bits=d.weight_bits)
-
-
 class Cell:
-    """One configuration served under one traffic mix."""
+    """One configuration served under one traffic mix.  ``family`` is the
+    configuration's family module (``bench/families/<family>.py``) and
+    ``d`` its sizes, as that module's ``dims`` gives them."""
 
-    def __init__(self, d: Dims, conf: Dict, mix: Dict, peaks: Dict,
+    def __init__(self, family, d, conf: Dict, mix: Dict, peaks: Dict,
                  seed: int, rate_hz: Optional[float] = None):
+        self.family = family
         self.d, self.conf, self.mix, self.peaks = d, conf, mix, peaks
         self.seed = int(seed)
         self.rate_hz = rate_hz
@@ -146,7 +113,7 @@ class Cell:
         from repro.serving import ServingEngine
 
         d = self.d
-        packed = make_weights(d, self.seed)
+        packed = self.family.make_weights(d, self.seed)
         share = float(self.conf["serving"]["resident_share"])
         if share >= 1.0:
             plan = PlacementPlan.uniform("l1mram", bits=d.weight_bits)
@@ -159,7 +126,7 @@ class Cell:
                 sizes_bits=d.weight_bits)
         self.plan = plan
         self.engine = ServingEngine(
-            to_model_config(d), packed, batch_slots=d.slots,
+            self.family.model_config(d), packed, batch_slots=d.slots,
             max_len=d.max_len, plan=plan, seed=self.seed % (1 << 31))
         del packed
         if share < 1.0:
@@ -182,13 +149,11 @@ class Cell:
         """Compile and run once every program the window can reach.  A few
         requests go through a scheduler first (the decode step and the
         first-token sampling, and they leave the cache as a program output
-        placed on the device, as every later call sees it); then each
-        prefill (bucket, kv_span) program the mix's lengths reach runs
-        once on that cache."""
-        import jax.numpy as jnp
+        placed on the device, as every later call sees it); then the
+        family's ``warm_prefill`` runs each prefill program the mix can
+        reach on that cache, called as the engine calls it for the family."""
         from repro.serving import Request
 
-        eng = self.engine
         sched = self.new_scheduler()
         pmin, pmax = traffic.length_range(self.mix, "prompt")
         stream = self.mix["streams"][-1]["name"]
@@ -199,17 +164,7 @@ class Cell:
                 stream=stream)
         sched.run_until_done()
         sched.close()
-        params = eng.fence_tick_params()
-        k = eng.slots
-        for bucket, span in prefill_shapes(self.mix, self.d):
-            fn = eng._prefill_for_bucket(bucket, True, span)
-            logits, cache = fn(params, jnp.zeros((k, bucket), jnp.int32),
-                               eng.cache, jnp.arange(k, dtype=jnp.int32),
-                               jnp.zeros((k,), jnp.int32))
-            del cache      # free the copy before the next program runs
-            # the engine reads each finished row's last logits eagerly
-            int(jnp.argmax(logits[k - 1, bucket - 1]))
-            del logits
+        self.family.warm_prefill(self.engine, self.mix, self.d)
 
     # -- the window -----------------------------------------------------------
     def _instrument(self, rec_decode, rec_prefill, rec_fence):
@@ -401,8 +356,8 @@ class Cell:
                 r.finished -= t0
         counters = {k: c1[k] - c0[k] for k in c0}
         return Window(
-            dims=self.d, peaks=self.peaks, window_s=t_close - t0,
-            requests=recs,
+            family=self.family, dims=self.d, peaks=self.peaks,
+            window_s=t_close - t0, requests=recs,
             ticks=int(counters["ticks"]), counters=counters,
             compiles=compiles, compile_s=compile_s, lateness_s=lateness,
             decode_calls=rec_decode, prefill_calls=rec_prefill,

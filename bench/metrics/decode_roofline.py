@@ -1,10 +1,9 @@
 """Share of the decode step's roofline: the least time of the decode
 calls over their device time.  A call's least time is the larger of its
 operations over peak and its bytes over HBM bandwidth, counted from
-shapes (bench/counts.py): packed weights with their scales, the tied
-embedding as LM head, and the keys and values of live positions only."""
-
-from bench import counts
+shapes by the cell's family (``decode_call`` in bench/families/): for the
+dense family, packed weights with their scales, the tied embedding as LM
+head, and the keys and values of live positions only."""
 
 DECODE = "jit__decode_impl"
 
@@ -13,7 +12,7 @@ def least_times(w):
     peak, bw = w.peaks["bf16_flops"], w.peaks["hbm_bytes_per_s"]
     out = []
     for keys in w.decode_calls:
-        flops, nbytes = counts.decode_call(w.dims, keys)
+        flops, nbytes = w.family.decode_call(w.dims, keys)
         out.append((flops / peak, nbytes / bw))
     return out
 

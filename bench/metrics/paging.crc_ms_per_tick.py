@@ -1,6 +1,7 @@
-"""Page-worker time per tick spent checking page CRCs (the ``tobytes``
-copies and ``zlib.crc32`` over every streamed byte): the program's
-``paging.crc`` spans summed over the traced window, over its ticks."""
+"""Page-worker time per tick spent checking page CRCs (``zlib.crc32`` over
+every streamed byte, read in place in fixed chunks, the larger buffers'
+chunks on a small shared thread pool): the program's ``paging.crc`` spans
+summed over the traced window, over its ticks."""
 
 from bench import program_spans
 

@@ -2,9 +2,9 @@
 pass in straightforward ``jax.numpy``, written from the published model
 descriptions and independent of the program's model code.
 
-- Weights: the int8 levels and per-row scales that ``bench/weights.py``
-  makes from the seed, dequantized as ``(level - 128) * scale``; norm
-  scales ``s`` apply as ``1 + s``.
+- Weights: the int8 levels and per-row scales that the dense family
+  (``bench/families/dense.py``) makes from the seed, dequantized as
+  ``(level - 128) * scale``; norm scales ``s`` apply as ``1 + s``.
 - Block: pre-norm residual.  ``h = x + Wo·attn(norm(x))``, then
   ``out = h + Wdown·(silu(Wgate·norm(h)) * Wup·norm(h))``.
 - Norm: RMSNorm (Qwen3, eps from the config) or LayerNorm without affine
@@ -33,8 +33,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from bench.model import Dims
-
 HEAD_ROWS = 128     # positions per block of the LM head
 
 
@@ -49,7 +47,7 @@ def _dequant(p, dt):
     return (w * p["scale"][:, None]).astype(dt)
 
 
-def _norm(x, scale, d: Dims):
+def _norm(x, scale, d):
     x32 = x.astype(jnp.float32)
     if d.norm == "rmsnorm":
         y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True)
@@ -89,7 +87,7 @@ def _wmm(x, p, dt, int8: bool):
     return (acc.astype(jnp.float32) * xs * p["scale"]).astype(dt)
 
 
-def _layer(x, p, d: Dims, dt, int8: bool = False):
+def _layer(x, p, d, dt, int8: bool = False):
     b, s, _ = x.shape
     h = _norm(x, p["attn_norm"].get("scale"), d)
     q = _wmm(h, p["attn"]["wq"], dt, int8)
@@ -120,7 +118,7 @@ def _layer(x, p, d: Dims, dt, int8: bool = False):
 
 
 @functools.lru_cache(maxsize=None)
-def _scorer(d: Dims, dtype: str):
+def _scorer(d, dtype: str):
     int8 = dtype == "int8"
     dt = jnp.dtype("float32" if int8 else dtype)
 
@@ -154,10 +152,11 @@ def _scorer(d: Dims, dtype: str):
     return score
 
 
-def score(d: Dims, weights: Dict, tokens, targets,
+def score(d, weights: Dict, tokens, targets,
           dtype: str = "float32") -> Tuple[jax.Array, ...]:
     """(best, argmax, target logit) at every position of ``tokens`` (B, S);
-    S must be a multiple of 128."""
+    S must be a multiple of 128.  ``d`` is the dense family's ``Dims``
+    (its sizes, norm and qk-norm)."""
     with jax.default_matmul_precision(
             "default" if dtype == "bfloat16" else "highest"):
         return _scorer(d, dtype)(weights, tokens, targets)

@@ -94,17 +94,17 @@ def measure(reg, workload: str, seed: int, seconds: float, trace: bool,
     object of the last line."""
     from bench import correct
     from bench.harness import Cell, clock
-    from bench.model import dims
 
     cell_doc = reg.workload(workload)
     conf = reg.config(cell_doc["config"])
     mix = reg.mix(cell_doc["traffic"])
-    d = dims(cell_doc["config"], conf)
+    family = reg.family(conf["model"]["family"])
+    d = family.dims(cell_doc["config"], conf)
     dev = devices[0]
     peaks = reg.peaks(dev.device_kind)
     _log(f"device: platform={dev.platform} kind={dev.device_kind} "
          f"count={len(devices)}")
-    cell = Cell(d, conf, mix, peaks, seed, rate_hz=rate_hz)
+    cell = Cell(family, d, conf, mix, peaks, seed, rate_hz=rate_hz)
     t_build = clock()
     cell.build()
     t_warm = clock()
@@ -161,7 +161,8 @@ def measure(reg, workload: str, seed: int, seconds: float, trace: bool,
                                    idle_gaps=w.trace["idle_gaps"])
     cell.free()
     t = clock()
-    chk = correct.check(d, conf, seed, w.requests, controls=controls)
+    chk = correct.check(family, reg.reference(conf["reference"]), d, conf,
+                        seed, w.requests, controls=controls)
     _log(f"reference: {clock() - t:.3f} s over {chk['sequences']} requests, "
          f"{chk['tokens']} served tokens ({chk['preempted']} preempted)")
     limit = conf["correct"][correct.NUMBER]

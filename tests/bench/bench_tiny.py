@@ -41,7 +41,11 @@ XR = {
 
 # trackers that preempt assistants: every slot holds an assistant when a
 # tracker comes due, so each tracker takes a slot from one, which is
-# restored once a slot frees
+# restored once a slot frees.  Assistants ask for 320 a second x 88 tokens
+# = 28,160 tokens/s; four slots serve four tokens a tick, so a backlog of
+# assistants holds the slots whenever a tick takes over 0.14 ms.  It
+# still rests on the host's speed, with a wide margin: a tick of this tiny
+# model takes about 3 ms on a CPU
 PREEMPT = {
     "loop": "open", "rate_hz": 20,
     "scheduler": {"prefill_chunk": 16, "token_budget": 24,
@@ -52,9 +56,9 @@ PREEMPT = {
          "prompt": {"dist": "uniform", "lo": 4, "hi": 8},
          "new_tokens": {"dist": "fixed", "value": 2}},
         {"name": "assistant", "priority": 0, "deadline_ms": None,
-         "xr": False, "arrival": {"kind": "poisson", "per_rate": 2.0},
+         "xr": False, "arrival": {"kind": "periodic", "per_rate": 16.0},
          "prompt": {"dist": "uniform", "lo": 8, "hi": 24},
-         "new_tokens": {"dist": "uniform", "lo": 8, "hi": 16}}],
+         "new_tokens": {"dist": "uniform", "lo": 80, "hi": 96}}],
 }
 
 BATCH = {

@@ -1,13 +1,15 @@
-"""Operation and byte counts from shapes, checked by hand at a tiny size
-and at the cells' published sizes."""
+"""Operation and byte counts from shapes (the dense family's), checked by
+hand at a tiny size and at the cells' published sizes."""
 
 import json
 from pathlib import Path
 
 import pytest
 
-from bench import counts
-from bench.model import dims
+from bench.registry import Registry
+
+counts = Registry().family("dense")
+dims = counts.dims
 
 CONFIGS = Path(__file__).resolve().parents[2] / "bench" / "configs"
 
@@ -53,3 +55,22 @@ def test_bench_counts_at_published_sizes(name, params, weight_mb):
     # decode of 16 slots at 300 live positions each is bound by bytes
     flops, nbytes = counts.decode_call(d, [300] * 16)
     assert nbytes / 819e9 > flops / 197e12
+
+
+# the dense counts at the published sizes, as the harness read them before
+# the family modules: weight_bytes, matmul_params, kv_row_bytes,
+# decode_call(d, [129, 512]), prefill_flops(d, 512, 131328)
+GOLDEN = {
+    "qwen3-0.6b": (1_064_366_080, 595_984_384, 229_376,
+                   (2_530_967_552, 1_211_404_288), 640_411_500_544),
+    "olmo-1b-paged": (1_487_536_128, 1_176_764_416, 262_144,
+                      (4_791_074_816, 1_655_586_816), 1_222_220_185_600),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GOLDEN))
+def test_bench_dense_counts_golden(name):
+    d = dims(name, json.loads((CONFIGS / f"{name}.json").read_text()))
+    assert (counts.weight_bytes(d), counts.matmul_params(d),
+            counts.kv_row_bytes(d), counts.decode_call(d, [129, 512]),
+            counts.prefill_flops(d, 512, 131328)) == GOLDEN[name]

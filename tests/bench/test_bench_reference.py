@@ -9,10 +9,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from bench.harness import to_model_config
-from bench.model import dims
-from bench.references import dense_decoder
-from bench.weights import make_weights
+from bench.registry import Registry
+
+dense = Registry().family("dense")
+dims, make_weights, model_config = (dense.dims, dense.make_weights,
+                                    dense.model_config)
+dense_decoder = Registry().reference("dense_decoder")
 
 BLOCKS = {
     "qk_norm_gqa": dict(model={"family": "dense", "norm": "rmsnorm",
@@ -46,7 +48,7 @@ def test_bench_reference_matches_program_forward(block):
     rng = np.random.default_rng(0)
     tokens = rng.integers(0, d.vocab, (2, 128)).astype(np.int32)
     logits = np.asarray(tfm.forward(w, jnp.asarray(tokens),
-                                    to_model_config(d)))
+                                    model_config(d)))
     targets = rng.integers(0, d.vocab, (2, 128)).astype(np.int32)
     best, arg, at = dense_decoder.score(d, w, tokens, targets)
     np.testing.assert_allclose(np.asarray(best), logits.max(-1),

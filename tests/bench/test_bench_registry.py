@@ -31,7 +31,9 @@ def test_bench_every_named_part_exists():
         assert (ROOT / c["file"]).is_file()
         assert c["name"] in reg.configs()
     for w in doc["workloads"]:
-        reg.config(w["config"])
+        conf = reg.config(w["config"])
+        reg.family(conf["model"]["family"])
+        assert callable(reg.reference(conf["reference"]).score)
         reg.mix(w["traffic"])
         assert reg.metrics_for(w["name"], "per_layer")
         names = [m["name"] for m in reg.metrics_for(w["name"], "end_to_end")]

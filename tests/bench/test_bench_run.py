@@ -171,13 +171,13 @@ def test_bench_traced_counts_cover_only_the_window(tmp_path, monkeypatch):
     before the window opens, is not among them."""
     from bench import xplane
     from bench.harness import Cell
-    from bench.model import dims
 
     monkeypatch.setattr(xplane, "find_trace", lambda d: d)
     monkeypatch.setattr(xplane, "reduce_trace", lambda p: {})
     reg = bench_tiny.registry(tmp_path)
     conf = reg.config("tiny")
-    cell = Cell(dims("tiny", conf), conf, reg.mix("batch-tiny"),
+    fam = reg.family(conf["model"]["family"])
+    cell = Cell(fam, fam.dims("tiny", conf), conf, reg.mix("batch-tiny"),
                 reg.peaks("cpu"), SEED)
     cell.build()
     cell.warm()

@@ -54,14 +54,15 @@ def test_bench_trace_readers_on_recorded_trace():
     stay within 0-100 %, and a reader with nothing to read is silent."""
     import types
 
-    from bench.model import dims
     from bench.registry import Registry
 
     reg = Registry()
-    d = dims("olmo-1b-paged", reg.config("olmo-1b-paged"))
+    conf = reg.config("olmo-1b-paged")
+    fam = reg.family(conf["model"]["family"])
+    d = fam.dims("olmo-1b-paged", conf)
     r = xplane.reduce_trace(str(TRACE))
     w = types.SimpleNamespace(
-        dims=d, peaks=reg.peaks("TPU v5 lite"), trace=r,
+        family=fam, dims=d, peaks=reg.peaks("TPU v5 lite"), trace=r,
         decode_calls=[[200 + i] * 16 for i in range(3)], prefill_calls=[],
         fences=[(0.0, 0), (0.5, 537919488), (1.0, 2 * 537919488)])
     assert 20 < reg.reader("engine.decode_ms")(w) < 40
@@ -72,7 +73,8 @@ def test_bench_trace_readers_on_recorded_trace():
     assert reg.reader("paging.wire_mb_per_tick")(w) == 537.919488
     assert reg.reader("paging.exposed_ms_per_tick")(w) == 500.0
     assert reg.reader("engine.prefill_ms_per_ktok")(w) is None
-    empty = types.SimpleNamespace(dims=d, peaks=w.peaks, trace=None,
+    empty = types.SimpleNamespace(family=fam, dims=d, peaks=w.peaks,
+                                  trace=None,
                                   decode_calls=[], prefill_calls=[],
                                   fences=[])
     for name in ("engine.decode_ms", "decode_roofline", "decode.mfu",
